@@ -27,6 +27,7 @@ from repro.core import (NonconvexLogistic, SweepSpec, mlp_lm_objective,
                         register_objective)
 from repro.data.libsvm import make_synthetic_libsvm
 from repro.service import SweepService
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

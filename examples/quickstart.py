@@ -50,6 +50,7 @@ from repro.core.objective import _fixed_order_sum
 from repro.data.libsvm import make_synthetic_libsvm
 from repro.server import FlushPolicy, SweepClient, SweepServer
 from repro.service import SweepService, cache_stats
+from repro.utils.compile_cache import enable_compile_cache
 
 
 class Ridge(Objective):
@@ -194,4 +195,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ from repro.configs import list_configs, reduced_config
 from repro.models.factory import build_model
 from repro.serve.loop import generate
 from repro.sharding.rules import init_from_defs
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -51,4 +52,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -30,6 +30,7 @@ from repro.data.libsvm import make_synthetic_libsvm
 from repro.server import (FairShare, FlushPolicy, SweepClient, SweepServer,
                           snapshot)
 from repro.service import SweepService, cache_stats, clear_cache
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -123,4 +124,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

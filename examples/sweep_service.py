@@ -25,6 +25,7 @@ from repro.core import (LogisticRegression, SweepSpec, make_grid, run_sweep,
                         svrg_sweep_spec)
 from repro.data.libsvm import make_synthetic_libsvm
 from repro.service import SweepService, cache_stats, clear_cache
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -95,4 +96,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ from repro.config import ModelConfig, SVRGConfig, TrainConfig
 from repro.data.synthetic_lm import SyntheticLMDataset
 from repro.models.factory import build_model
 from repro.train.loop import train
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def model_cfg(small: bool) -> ModelConfig:
@@ -59,4 +60,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

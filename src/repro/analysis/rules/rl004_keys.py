@@ -14,7 +14,7 @@ The checker is structural, anchored on the shapes that actually exist in
   1. Every field of the ``_Resolved`` NamedTuple must either appear as an
      ``r.<field>`` element of the group-key tuple built via
      ``groups.setdefault((...), ...)`` in ``plan_sweep``, or be packed
-     into the per-row runtime arrays in ``_dispatch_group``
+     into the per-row runtime arrays in ``_row_args`` / ``_dispatch_group``
      (``resolved[c].<field>`` / ``specs[c].<field>``). A field that is
      neither keyed nor row-data can silently alias groups — exactly the
      buf_len failure. Fields that are genuinely derived/accounting-only
@@ -106,15 +106,18 @@ def _check_sweep(sf: SourceFile, out: List[Diagnostic]) -> None:
             "the group-key anchor RL004 checks against is gone; restore "
             "it or update the checker"))
         return
-    dispatch = _find_func(sf.tree, "_dispatch_group")
-    packed = _packed_attrs(dispatch) if dispatch is not None else set()
+    packed: Set[str] = set()
+    for name in ("_row_args", "_dispatch_group"):
+        func = _find_func(sf.tree, name)
+        if func is not None:
+            packed |= _packed_attrs(func)
     for field in _resolved_fields(cls):
         name = field.target.id
         if name not in keyed and name not in packed:
             out.append(Diagnostic(
                 sf.path, field.lineno, "RL004",
                 f"_Resolved.{name} reaches neither the plan_sweep group "
-                "key nor _dispatch_group's per-row runtime arrays — specs "
+                "key nor _row_args's per-row runtime arrays — specs "
                 f"differing only in {name!r} would alias one compiled "
                 "program (the PR-3 buf_len bug); key it, pack it, or "
                 "suppress here with the derivation argument"))

@@ -2,11 +2,11 @@
 
 ``src/repro/kernels/*/kernel.py`` holds the Pallas megakernel bodies and
 their `pallas_call` builders. Those modules are imported inside
-`runner_key` (the fused-mode facet) and traced inside jit; anything
+`runner_key` (the kernel-mode facet) and traced inside jit; anything
 effectful there is either silently dropped by tracing (prints), breaks
 interpret/compiled parity (host callbacks), or makes the compiled program
 depend on ambient process state that the cache key cannot see
-(environment sniffing — the exact hazard `_fused_mode_key` exists to
+(environment sniffing — the exact hazard `_kernel_mode_key` exists to
 prevent: mode decisions belong in `repro.kernels.dispatch`, resolved at
 KEY time, never inside a kernel module).
 

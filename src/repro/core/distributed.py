@@ -29,7 +29,6 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.config import SVRGConfig
 from repro.core.compression import ErrorFeedbackState, compressed_update
@@ -193,12 +192,12 @@ def bounded_staleness_epoch(
 
     keys = jax.random.split(rng, max(2, num_workers))[:num_workers]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         worker,
         mesh=mesh,
         in_specs=(P(), P(), P(), P("data"), P("data"), P("data")),
         out_specs=(P(), P("data")),
-        check_rep=False,
+        check_vma=False,
     )
     new_params, new_residual = fn(params, w_snap, g_snap, local_batches,
                                   keys, ef.residual)

@@ -91,7 +91,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 import os
 
@@ -104,6 +103,7 @@ from repro.core.asysvrg import (
 )
 from repro.core.hogwild import _hogwild_epochs_core, _resolve_hogwild_steps
 from repro.core.objective import Objective, get_objective, params_from_flat
+from repro.kernels.dispatch import fused_sweep_mode
 from repro.obs import ledger as _ledger
 from repro.obs.trace import tracer as _tracer
 from repro.sharding.context import current_mesh
@@ -117,10 +117,11 @@ _DATA_AXIS = "data"
 # engine modes: how a group's epoch scan executes. "vmap" batches the
 # per-row epochs cores with jax.vmap (per-update XLA op dispatch); "fused"
 # maps the row axis onto a Pallas grid and runs the whole (group × epoch)
-# scan as ONE megakernel launch (repro.kernels.sweep_epoch) — compiled on
-# TPU, Pallas-interpreter elsewhere, where it is BIT-IDENTICAL to the vmap
-# path (tests/test_kernel_sweep.py). "" on a spec inherits the process
-# default: $REPRO_SWEEP_ENGINE, else "vmap".
+# scan as ONE megakernel launch (repro.kernels.sweep_epoch) — under the
+# Pallas interpreter, where it is BIT-IDENTICAL to the vmap path
+# (tests/test_kernel_sweep.py). On a TPU backend "fused" is refused at plan
+# time (`repro.kernels.dispatch.FUSED_TPU_BLOCKERS`). "" on a spec inherits
+# the process default: $REPRO_SWEEP_ENGINE, else "vmap".
 ENGINE_MODES = ("vmap", "fused")
 _ENGINE_MODE_ENV = "REPRO_SWEEP_ENGINE"
 
@@ -339,11 +340,14 @@ def _resolve(obj: Objective, spec: SweepSpec,
     Raises (rather than letting a negative M̃ surface as a cryptic
     trace-time shape error) for non-positive resolved totals — this is the
     validation the service relies on to reject a bad spec at submit time.
+    A fused row on a TPU backend raises here too (`fused_sweep_mode`).
     """
     epochs = spec.epochs or default_epochs
     if epochs < 1:
         raise ValueError(f"resolved epochs must be >= 1, got {epochs}")
     fused = (spec.engine_mode or default_engine_mode()) == "fused"
+    if fused:
+        fused_sweep_mode()
 
     if spec.algo == "hogwild":
         _, total, tau = _resolve_hogwild_steps(obj.n, spec.num_threads,
@@ -547,7 +551,6 @@ def _group_fn(engine: str, *, obj: Objective, num_data: int, epochs: int,
     bit-identical.
     """
     if fused:
-        from repro.kernels.dispatch import fused_sweep_mode
         from repro.kernels.sweep_epoch import fused_group_fn
         return (fused_group_fn(obj, num_data, engine=engine, epochs=epochs,
                                total=total, buf_len=buf_len, option=option,
@@ -569,15 +572,15 @@ def _shard_group_fn(fn, mesh: Mesh, num_data: int, num_row: int):
 
     Each device runs the identical program over its row shard and NO
     collective crosses rows, which is why sharded rows stay bit-identical
-    to the unsharded path. (`check_rep=False`: mesh axes other than `data`
+    to the unsharded path. (`check_vma=False`: mesh axes other than `data`
     — e.g. `model` in the production mesh — replicate the rows redundantly,
     which is deterministic and harmless.)
     """
     spec = P(_DATA_AXIS)
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(P(),) * num_data + (spec,) * num_row,
-                     out_specs=(spec, spec),
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(P(),) * num_data + (spec,) * num_row,
+                         out_specs=(spec, spec),
+                         check_vma=False)
 
 
 def _accumulate_passes(ppe: Sequence[float], epochs_per_row: np.ndarray,
@@ -614,6 +617,30 @@ def _write_row_history(dst_row: np.ndarray, hist_row: np.ndarray,
         dst_row[group_epochs + 1:] = hist_row[-1]
 
 
+def _row_args(engine: str, specs: Sequence[SweepSpec],
+              resolved: Sequence[_Resolved], members: Sequence[int],
+              w_init) -> Tuple[jnp.ndarray, ...]:
+    """A group runner's row-leading arguments for ``members``, in calling
+    order (after the objective's data args; `_NUM_ROW_ARGS` of them)."""
+    keys = jax.vmap(jax.random.PRNGKey)(
+        jnp.asarray([specs[c].seed for c in members]))
+    etas = jnp.asarray([specs[c].step_size for c in members], jnp.float32)
+    taus_a = jnp.asarray([resolved[c].tau for c in members], jnp.int32)
+    scheme_ids = jnp.asarray([resolved[c].scheme_id for c in members],
+                             jnp.int32)
+    delay_ids = jnp.asarray([resolved[c].delay_id for c in members],
+                            jnp.int32)
+    row_epochs = jnp.asarray([resolved[c].epochs for c in members],
+                             jnp.int32)
+    w0_rows = jnp.tile(w_init[None, :], (len(members), 1))
+
+    if engine == _ENGINE_HOGWILD:
+        decays = jnp.asarray([specs[c].decay for c in members], jnp.float32)
+        return (keys, etas, decays, taus_a, scheme_ids, delay_ids,
+                row_epochs, w0_rows)
+    return (keys, etas, taus_a, scheme_ids, delay_ids, row_epochs, w0_rows)
+
+
 def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
                     resolved: Sequence[_Resolved], members: Sequence[int],
                     key_: _GroupKey, group_epochs: int, w_init,
@@ -631,26 +658,7 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
     from repro.service.cache import get_group_runner
 
     _, engine, total, option, buf_len, fused = key_
-    keys = jax.vmap(jax.random.PRNGKey)(
-        jnp.asarray([specs[c].seed for c in members]))
-    etas = jnp.asarray([specs[c].step_size for c in members], jnp.float32)
-    taus_a = jnp.asarray([resolved[c].tau for c in members], jnp.int32)
-    scheme_ids = jnp.asarray([resolved[c].scheme_id for c in members],
-                             jnp.int32)
-    delay_ids = jnp.asarray([resolved[c].delay_id for c in members],
-                            jnp.int32)
-    row_epochs = jnp.asarray([resolved[c].epochs for c in members],
-                             jnp.int32)
-    w0_rows = jnp.tile(w_init[None, :], (len(members), 1))
-
-    if engine == _ENGINE_HOGWILD:
-        decays = jnp.asarray([specs[c].decay for c in members], jnp.float32)
-        args = (keys, etas, decays, taus_a, scheme_ids, delay_ids,
-                row_epochs, w0_rows)
-    else:
-        args = (keys, etas, taus_a, scheme_ids, delay_ids, row_epochs,
-                w0_rows)
-
+    args = _row_args(engine, specs, resolved, members, w_init)
     runner = get_group_runner(engine, group_epochs=group_epochs, total=total,
                               option=option, buf_len=buf_len,
                               drop_prob=drop_prob, mesh=mesh, obj=obj,

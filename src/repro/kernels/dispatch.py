@@ -8,7 +8,10 @@ picked an execution mode and tests had no uniform way to force one.
 
   * ``REPRO_KERNEL_MODE`` env var, when set, WINS — ``compiled`` /
     ``interpret`` / ``reference``. This is what the CI ``kernels-interpret``
-    lane and local debugging use to force every kernel down one path.
+    lane and local debugging use to force every kernel down one path. On a
+    TPU backend ``interpret`` is refused: the interpreter would hide the
+    device the run claims to use. ``reference`` stays allowed there, to
+    measure a kernel against its jnp oracle on the chip.
   * Otherwise the caller's ``force_kernel`` / ``interpret`` flags and a
     backend sniff reproduce the historical per-kernel behaviour exactly:
     the Pallas body runs compiled on TPU (interpret-mode when asked),
@@ -17,9 +20,10 @@ picked an execution mode and tests had no uniform way to force one.
     jnp reference path.
 
 Modes:
-  ``compiled``  — ``pl.pallas_call(..., interpret=False)`` (real Mosaic
-                  lowering; TPU/GPU only — NOT validated on this repo's
-                  CPU CI, see the ROADMAP real-accelerator item).
+  ``compiled``  — ``pl.pallas_call(..., interpret=False)``: real Mosaic
+                  lowering, the default on TPU. `chip_smoke.py` runs it on
+                  the chip; tests/test_tpu_compile.py compiles it for a
+                  described v5e topology.
   ``interpret`` — the kernel BODY executes under the Pallas interpreter
                   (plain XLA ops, any backend, bit-exact vs the same body
                   compiled only up to backend reduction order).
@@ -36,12 +40,29 @@ KERNEL_MODE_ENV = "REPRO_KERNEL_MODE"
 _MODES = ("compiled", "interpret", "reference")
 
 
+# Why the fused megakernel cannot run on a TPU today (found by compiling
+# it for a described v5e topology; ROADMAP S6 holds the rewrite).
+FUSED_TPU_BLOCKERS = (
+    "the fused sweep-epoch megakernel does not compile for TPU: Mosaic "
+    "refuses its (1, 2) PRNG-key row block, which is not aligned to the "
+    "(8, 128) tiling, and every data array enters as one constant full "
+    "block, which puts the whole dataset in VMEM. Use engine_mode='vmap' "
+    "on TPU.")
+
+
 def env_mode() -> str:
-    """The ``REPRO_KERNEL_MODE`` override, validated; "" when unset."""
+    """The ``REPRO_KERNEL_MODE`` override, validated; "" when unset.
+    ``interpret`` on a TPU backend raises instead of running the Pallas
+    interpreter where the chip is expected."""
     mode = os.environ.get(KERNEL_MODE_ENV, "").strip().lower()
     if mode and mode not in _MODES:
         raise ValueError(
             f"{KERNEL_MODE_ENV}={mode!r} — expected one of {_MODES}")
+    if mode == "interpret" and kernel_backend() == "tpu":
+        raise ValueError(
+            f"{KERNEL_MODE_ENV}=interpret on a TPU backend would run the "
+            "Pallas interpreter instead of the chip; unset it (compiled) or "
+            "set 'reference' to measure the jnp oracle")
     return mode
 
 
@@ -68,19 +89,16 @@ def kernel_mode(interpret: bool = False, force_kernel: bool = False) -> str:
 def fused_sweep_mode() -> str:
     """'compiled' | 'interpret' for the fused sweep megakernel.
 
-    The megakernel has no separate jnp reference — the vmap engine IS its
-    reference — so 'reference' is not a meaningful mode here: auto picks
-    compiled on TPU and interpret everywhere else (where the interpreter
-    is bit-exact to the vmap path), and an env override of ``reference``
-    degrades to interpret. ``compiled``/``interpret`` overrides win as
-    usual.
+    On a TPU backend this raises `FUSED_TPU_BLOCKERS`: the kernel does not
+    lower there, and substituting the interpreter or the vmap path would
+    hide that. Elsewhere the megakernel has no separate jnp reference — the
+    vmap engine IS its reference — so 'reference' is not a meaningful mode:
+    it runs the interpreter (bit-exact to the vmap path), as does the
+    default; a ``compiled`` override wins.
     """
-    mode = env_mode()
-    if mode == "compiled":
-        return "compiled"
-    if mode in ("interpret", "reference"):
-        return "interpret"
-    return "compiled" if kernel_backend() == "tpu" else "interpret"
+    if kernel_backend() == "tpu":
+        raise ValueError(FUSED_TPU_BLOCKERS)
+    return "compiled" if env_mode() == "compiled" else "interpret"
 
 
 def mode_tags(fused: bool) -> dict:
@@ -88,13 +106,11 @@ def mode_tags(fused: bool) -> dict:
     the tracer's ``execute`` spans by `repro.core.sweep._dispatch_group`
     so a trace answers "which lowering ran this request" without anyone
     re-deriving the mode later (it can change with the environment). The
-    resolution mirrors `_fused_mode_key`: vmap bodies report the backend
-    only; fused bodies add the resolved megakernel mode."""
-    tags = {"engine_mode": "fused" if fused else "vmap",
-            "backend": kernel_backend()}
-    if fused:
-        tags["kernel_mode"] = fused_sweep_mode()
-    return tags
+    resolution mirrors the runner-cache key (`_kernel_mode_key`): the
+    megakernel mode for fused bodies, the `svrg_update` mode for vmap."""
+    return {"engine_mode": "fused" if fused else "vmap",
+            "backend": kernel_backend(),
+            "kernel_mode": fused_sweep_mode() if fused else kernel_mode()}
 
 
 def use_pallas(interpret: bool = False, force_kernel: bool = False) -> bool:

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.utils.compat import tpu_compiler_params
-
 BLOCK_B = 128
 BLOCK_P = 512
 
@@ -64,7 +62,7 @@ def margins(X, y, w, interpret: bool = False):
         out_specs=pl.BlockSpec((BLOCK_B, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((BLOCK_B, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(X, w, y)
@@ -101,7 +99,7 @@ def grad_accum(X, c, interpret: bool = False):
         out_specs=pl.BlockSpec((BLOCK_P, 1), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((P, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((BLOCK_P, 1), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(X, c)

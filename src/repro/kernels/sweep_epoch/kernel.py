@@ -18,9 +18,10 @@ engine batches (`repro.core.asysvrg._asysvrg_epochs_core` /
 the body lowers to the identical XLA:CPU ops per row, and the engine's
 vmap-bitwise-stable contract (vmap == per-row bits) closes the loop — the
 fused path is BIT-IDENTICAL to the vmap path in interpret mode
-(tests/test_kernel_sweep.py). Compiled (Mosaic) lowering targets TPU and is
-NOT validated in this CPU container — see the ROADMAP real-accelerator
-revalidation item.
+(tests/test_kernel_sweep.py). Mosaic refuses the compiled lowering for
+TPU (the (1, 2) key row block is not (8, 128)-aligned, and the full data
+blocks put the dataset in VMEM), so a TPU backend rejects the fused engine
+at plan time (`repro.kernels.dispatch.FUSED_TPU_BLOCKERS`, ROADMAP S6).
 
 Operand layout (built by `repro.kernels.sweep_epoch.ops`):
 
@@ -38,8 +39,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.utils.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _const_index_map(ndim: int):
@@ -114,7 +114,7 @@ def sweep_epoch_call(row_fn, data, row_args, *, epochs: int, dim: int,
             jax.ShapeDtypeStruct((rows, epochs + 1), jnp.float32),
         ],
         # rows are independent: the grid axis is embarrassingly parallel
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*data_ops, *row_ops)

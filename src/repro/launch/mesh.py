@@ -3,13 +3,18 @@
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS before first jax init,
 and smoke tests must keep seeing 1 CPU device.
-
-Mesh construction goes through repro.utils.compat so the same code runs on
-JAX 0.4.x (no AxisType) and newer releases (Auto axis types requested).
 """
 from __future__ import annotations
 
-from repro.utils.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis ``Auto``: the sweep's ``shard_map``
+    and the sharding rules place arrays explicitly, never through types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -47,7 +52,6 @@ def make_sweep_mesh(num_devices: int | None = None):
     additionally makes distinct-but-equal meshes share compiled-runner
     cache entries.
     """
-    import jax
     n = num_devices or len(jax.devices())
     mesh = _SWEEP_MESHES.get(n)
     if mesh is None:

@@ -41,6 +41,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.core import sweep as _sweep
+from repro.kernels.dispatch import fused_sweep_mode, kernel_mode
 from repro.obs import ledger as _ledger
 from repro.obs.trace import tracer as _tracer
 from repro.sharding.context import mesh_fingerprint
@@ -136,18 +137,18 @@ _MAX_RUNNERS = 64
 
 _RunnerKey = Tuple  # (engine, M̃, option, buf_len, epochs, drop_prob,
 #                     mesh fingerprint, objective static key,
-#                     per-data-leaf (shape, dtype), fused kernel mode)
+#                     per-data-leaf (shape, dtype), (body, kernel mode))
 
 
-def _fused_mode_key(fused: bool) -> Optional[str]:
-    """The cache-key facet for the engine body: None for the vmap path,
-    else the RESOLVED megakernel mode ('interpret' | 'compiled'). Resolving
-    at key time means flipping ``REPRO_KERNEL_MODE`` mid-process can never
-    serve a runner built for the other lowering."""
-    if not fused:
-        return None
-    from repro.kernels.dispatch import fused_sweep_mode
-    return fused_sweep_mode()
+def _kernel_mode_key(fused: bool) -> Tuple[str, str]:
+    """The cache-key facet for the engine body and its RESOLVED kernel
+    lowering: ("vmap", the `svrg_update` mode the inner update traces
+    with) or ("fused", the megakernel mode). Resolving at key time means
+    flipping ``REPRO_KERNEL_MODE`` mid-process can never serve a runner
+    built for the other lowering."""
+    if fused:
+        return ("fused", fused_sweep_mode())
+    return ("vmap", kernel_mode())
 
 
 def runner_key(engine: str, *, group_epochs: int, total: int, option: int,
@@ -162,7 +163,7 @@ def runner_key(engine: str, *, group_epochs: int, total: int, option: int,
                      for a in obj.data_args())
     return (engine, int(total), int(option), int(buf_len), int(group_epochs),
             float(drop_prob), mesh_fingerprint(mesh),
-            obj.runner_static_key(), data_sig, _fused_mode_key(fused))
+            obj.runner_static_key(), data_sig, _kernel_mode_key(fused))
 
 
 def _counted(fn):

@@ -69,8 +69,8 @@ def test_elastic_restore_with_shardings(tmp_path):
     path where the mesh changed between save and restore."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.utils.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
     ck = Checkpointer(str(tmp_path))
     ck.save(_state(3.0), step=3)
     sh = {"params": {"w": NamedSharding(mesh, P("data", "model"))},

@@ -188,8 +188,8 @@ def test_runner_cache_keys_fused_separately(obj):
     k_vmap = runner_key("asysvrg", **common)
     k_fused = runner_key("asysvrg", fused=True, **common)
     assert k_vmap != k_fused
-    assert k_vmap[-1] is None
-    assert k_fused[-1] == dispatch.fused_sweep_mode()
+    assert k_vmap[-1] == ("vmap", dispatch.kernel_mode())
+    assert k_fused[-1] == ("fused", dispatch.fused_sweep_mode())
 
 
 # ------------------------------------------------- unified kernel dispatch
@@ -225,4 +225,5 @@ def test_kernel_mode_historical_contract(monkeypatch):
     monkeypatch.setattr(dispatch, "kernel_backend", lambda: "tpu")
     assert dispatch.kernel_mode() == "compiled"
     assert dispatch.kernel_mode(interpret=True) == "interpret"
-    assert dispatch.fused_sweep_mode() == "compiled"
+    with pytest.raises(ValueError, match="Mosaic"):
+        dispatch.fused_sweep_mode()

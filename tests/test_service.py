@@ -108,6 +108,24 @@ def test_cache_keys_separate_static_dims(obj):
     assert runner_key("asysvrg", **{**k, "obj": obj2}) == base
 
 
+def test_vmap_runner_keyed_on_kernel_mode(obj, monkeypatch):
+    """Flipping $REPRO_KERNEL_MODE mid-process re-keys the vmap runner: a
+    runner traced with the jnp reference update is never served once the
+    `svrg_update` mode resolves to the Pallas kernel body, and back."""
+    from repro.kernels.dispatch import KERNEL_MODE_ENV
+    from repro.service.cache import get_group_runner
+    k = dict(group_epochs=2, total=100, option=2, buf_len=4,
+             drop_prob=0.02, mesh=None, obj=obj)
+    monkeypatch.setenv(KERNEL_MODE_ENV, "reference")
+    reference = get_group_runner("asysvrg", **k)
+    monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
+    interpret = get_group_runner("asysvrg", **k)
+    assert interpret is not reference
+    assert runner_key("asysvrg", **k)[-1] == ("vmap", "interpret")
+    monkeypatch.setenv(KERNEL_MODE_ENV, "reference")
+    assert get_group_runner("asysvrg", **k) is reference
+
+
 def test_clear_cache_resets(obj):
     run_sweep(obj, 1, _grid_a()[:1])
     assert cache_size() >= 1

@@ -6,7 +6,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_host_mesh
-from repro.utils.compat import make_mesh
 from repro.sharding.context import constrain, mesh_context
 from repro.sharding.rules import (
     ParamDef, defs_to_shape_structs, defs_to_shardings, init_from_defs,
@@ -26,7 +25,7 @@ def test_pspec_basic(mesh):
 def test_pspec_divisibility_fallback(mesh):
     # dim 3 not divisible by... host mesh is 1x1 so everything divides;
     # build a fake 2-way check via rules on a (2,) mesh axis
-    m = make_mesh((1, 1), ("data", "model"))
+    m = make_host_mesh()
     spec = logical_to_pspec((3, 7), ("embed", "mlp"), m)
     assert spec == P("data", "model")   # 1-way always divides
 
