@@ -43,9 +43,9 @@ REQUIRED_KEYS: Dict[str, Tuple[str, ...]] = {
     # warm deltas (tracer/histograms/progress/telemetry)
     "obs_overhead": ("tracer_off_s", "tracer_on_s", "overhead_frac",
                      "http_smoke", "features"),
-    # obs-smoke lane: live-progress stream + per-group performance ledger
-    # over one multi-group run_job (see _check_progress_ledger)
-    "progress_ledger": ("groups", "progress", "watchdog"),
+    # obs-smoke lane: live-progress stream + divergence watchdog over one
+    # multi-group run_job
+    "progress_ledger": ("progress", "watchdog"),
     # written by `python -m repro.analysis --json-out` in the repro-lint
     # CI lane; diagnostics must be [] for the lane to pass, but the
     # artifact records suppression counts for trend tooling either way
@@ -75,27 +75,6 @@ def _check_kernel_sweep(payload: dict) -> List[str]:
     return errs
 
 
-# every ledger group entry the perf-trajectory tooling reads: compile
-# attribution, FLOPs (cost_analysis or analytic) and the attained-vs-
-# roofline fraction (acceptance criterion: >= 2 compiled groups).
-_LEDGER_GROUP_KEYS = ("compile_s", "flops", "attained_frac",
-                      "warm_wall_min_s", "dispatches", "compiles")
-
-
-def _check_progress_ledger(payload: dict) -> List[str]:
-    errs = []
-    groups = payload.get("groups")
-    if not isinstance(groups, dict) or len(groups) < 2:
-        return [f"groups: expected a dict of >= 2 ledger entries, "
-                f"got {groups!r:.80}"]
-    for label, entry in groups.items():
-        missing = [k for k in _LEDGER_GROUP_KEYS
-                   if not isinstance(entry, dict) or k not in entry]
-        if missing:
-            errs.append(f"groups[{label!r}]: missing keys {missing}")
-    return errs
-
-
 def check_file(path: str) -> List[str]:
     """All schema violations for one artifact (empty list = valid)."""
     name = os.path.basename(path)[len("BENCH_"):-len(".json")]
@@ -111,8 +90,6 @@ def check_file(path: str) -> List[str]:
             for k in REQUIRED_KEYS.get(name, ()) if k not in payload]
     if name == "kernel_sweep" and not errs:
         errs += _check_kernel_sweep(payload)
-    if name == "progress_ledger" and not errs:
-        errs += _check_progress_ledger(payload)
     return errs
 
 

@@ -57,7 +57,7 @@ _PROM_LINE = re.compile(
 # span names every traced HTTP request must produce (pad appears because
 # the daemon installs a WidthRegistry; execute carries the engine tags)
 _EXPECTED_SPANS = {"submit", "plan", "coalesce", "pad", "dispatch",
-                   "execute", "demux"}
+                   "execute", "fetch", "demux", "encode"}
 
 
 def _specs(base_seed: int, rows: int = ROWS_PER_REQUEST,

@@ -1,15 +1,9 @@
-"""Live-observability smoke: progress streaming + performance ledger.
+"""Live-observability smoke: progress streaming + the divergence watchdog.
 
-Runs one multi-group job through ``SweepService.run_job`` with the whole
-PR-10 observability stack on — live progress bus, divergence watchdog,
-per-group performance ledger — and writes the schema-gated
-``BENCH_progress_ledger.json`` the perf-trajectory tooling keys on:
+Runs one multi-group job through ``SweepService.run_job`` with the live
+progress bus and the divergence watchdog on, and writes the schema-gated
+``BENCH_progress_ledger.json``:
 
-  * ``groups`` — the ledger snapshot, one entry per compiled group
-    runner. The gate (`benchmarks.check_artifacts`) requires >= 2 group
-    entries each carrying ``compile_s``, ``flops`` and ``attained_frac``
-    (XLA's own ``cost_analysis`` FLOPs when the backend provides them,
-    the analytic epoch model otherwise — ``flops_source`` says which).
   * ``progress`` — what the live stream delivered: slice events BEFORE
     the job finished, and per-row event losses that match the final
     `SweepResult` histories bit-for-bit (checked here, hard failure).
@@ -18,8 +12,7 @@ per-group performance ledger — and writes the schema-gated
     stays bit-identical; the artifact records the cancelled count.
 
 Two groups come from two ``inner_steps`` values (the group key includes
-the per-epoch update count), so both a cold compile and the ledger's
-roofline attribution are exercised per group.
+the per-epoch update count), so the job takes more than one slice.
 """
 from __future__ import annotations
 
@@ -32,7 +25,6 @@ from benchmarks.artifacts import write_bench_json
 from repro.checkpoint import Checkpointer
 from repro.core import LogisticRegression, SweepSpec
 from repro.data.libsvm import make_synthetic_libsvm
-from repro.obs.ledger import disable_ledger, enable_ledger
 from repro.obs.progress import disable_progress, enable_progress, \
     progress_bus
 from repro.obs.watchdog import Watchdog
@@ -42,10 +34,8 @@ WATCH_ID = "bench-progress-ledger"
 
 
 def _specs(rows_per_group: int):
-    """Two compiled groups (inner_steps 23 vs 46 — values no other
-    benchmark uses, so the cold-compile attribution holds even when this
-    runs after others in one process) plus one row that diverges
-    immediately — same group as the first, so the watchdog's re-dispatch
+    """Two compiled groups (inner_steps 23 vs 46) plus one row that
+    diverges immediately — same group as the first, so the watchdog's re-dispatch
     is a cache hit, not a new compile."""
     good = [SweepSpec(scheme="inconsistent", step_size=0.5, tau=3,
                       num_threads=4, inner_steps=steps, seed=7 * c + steps)
@@ -65,7 +55,6 @@ def run(quick: bool = False) -> dict:
     svc = SweepService(obj, epochs=epochs,
                        watchdog=Watchdog(policy="cancel_row"))
     enable_progress()
-    enable_ledger().clear()
     bus = progress_bus()
     bus.clear()
     try:
@@ -112,20 +101,8 @@ def run(quick: bool = False) -> dict:
                 f"watchdog should cancel exactly the step_size=1e30 row, "
                 f"got diverged rows {diverged.tolist()}")
 
-        groups = enable_ledger().snapshot()
-        if len(groups) < 2:
-            raise AssertionError(
-                f"expected >= 2 ledger group entries, got {sorted(groups)}")
-        for label, entry in groups.items():
-            for k in ("compile_s", "flops", "attained_frac"):
-                if not entry.get(k, 0.0) > 0.0:
-                    raise AssertionError(
-                        f"ledger entry {label}: {k} not populated "
-                        f"({entry.get(k)!r})")
-
         return {
             "dataset": "real-sim", "epochs": epochs, "rows": len(specs),
-            "groups": groups,
             "progress": {
                 "watch_id": WATCH_ID,
                 "events": len(events),
@@ -140,19 +117,12 @@ def run(quick: bool = False) -> dict:
         }
     finally:
         disable_progress(clear=True)
-        disable_ledger(clear=True)
 
 
 def main(quick: bool = True):
     out = run(quick=quick)
     write_bench_json("progress_ledger", out)
     print("name,us_per_call,derived")
-    for label, entry in sorted(out["groups"].items()):
-        print(f"ledger_{label},{entry['warm_wall_min_s'] * 1e6:.0f},"
-              f"compile_s={entry['compile_s']:.3f};"
-              f"flops={entry['flops']:.3e};"
-              f"attained_frac={entry['attained_frac']:.4f};"
-              f"src={entry.get('flops_source', '')}")
     print(f"progress_events,0,slices={out['progress']['slice_events']};"
           f"diverged={out['watchdog']['diverged_rows']}")
 

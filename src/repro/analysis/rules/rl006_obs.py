@@ -24,10 +24,11 @@ jit-traced numeric bodies, nested functions included) and anywhere in a
     ``.watch`` method calls;
   * the divergence watchdog: ``Watchdog`` / ``enforce_group`` /
     ``first_bad_epoch`` (host-side numpy inspection by contract);
-  * the performance ledger: ``ledger`` / ``enable_ledger`` /
-    ``disable_ledger`` / ``note_compile`` and ``.record_dispatch``
-    method calls;
   * any reference into ``repro.obs`` (aliased module access included).
+
+``jax.named_scope`` is the one instrument allowed there: it names the
+ops traced inside it in their metadata (a device trace's ``tf_op``
+path) and adds no op and no host call, so it cannot change the program.
 
 Fix: move the measurement to the call site that dispatches the jitted
 function (see `repro.core.sweep._dispatch_group` for the pattern), or
@@ -49,16 +50,13 @@ _TIMING_CALLS = {
     for suffix in ("", "_ns")
 }
 _TRACER_CALLS = {"tracer", "enable_tracing", "disable_tracing"}
-# live-obs entry points (PR 10): progress bus, watchdog, perf ledger —
-# all host-side by contract, so any call inside a jitted scope is a bug
+# live-obs entry points: progress bus and watchdog — both host-side by
+# contract, so any call inside a jitted scope is a bug
 _PROGRESS_CALLS = {"progress_bus", "ProgressBus", "enable_progress",
                    "disable_progress"}
 _WATCHDOG_CALLS = {"Watchdog", "enforce_group", "first_bad_epoch"}
-_LEDGER_CALLS = {"ledger", "enable_ledger", "disable_ledger",
-                 "note_compile"}
 _OBS_METHODS = {"span", "span_all", "span_active", "annotate", "new_trace",
-                "record_error", "observe", "publish", "watch",
-                "record_dispatch"}
+                "record_error", "observe", "publish", "watch"}
 
 
 def _kernel_module(path: str) -> bool:
@@ -78,8 +76,6 @@ def _why(node: ast.Call) -> str:
         return f"progress-bus call `{name}(...)`"
     if last in _WATCHDOG_CALLS:
         return f"watchdog call `{name}(...)`"
-    if last in _LEDGER_CALLS:
-        return f"ledger call `{name}(...)`"
     if "." in name and last in _OBS_METHODS:
         return f"obs recording call `{name}(...)`"
     return ""

@@ -61,6 +61,22 @@ SCHEME_IDS = {"consistent": 0, "inconsistent": 1, "unlock": 2}
 DELAY_IDS = {"zero": 0, "fixed": 1, "uniform": 2}
 _UNLOCK = SCHEME_IDS["unlock"]
 
+# Names of the epoch cores' device work. `jax.named_scope` writes them into
+# each op's metadata (the `tf_op` path of a device trace) and nowhere else:
+# no op, no host call, no result bit changes. A reader matches whole
+# components of that path.
+SNAPSHOT_SCOPE = "snapshot"         # the full-gradient pass at w_t
+INNER_STEP_SCOPE = "inner_step"     # one inner update (the scan's body)
+READ_SCOPE = "read"                 # read_dispatch, all scheme branches
+SAMPLE_GRAD_SCOPE = "sample_grad"   # one per-sample gradient
+DROP_MASK_SCOPE = "drop_mask"       # the unlock write-drop mask
+LOSS_SCOPE = "loss"                 # one fixed-order loss scan
+# each reader branch under its scheme's name, in SCHEME_IDS order
+READER_SCOPES = tuple(f"read_{s}" for s in SCHEME_IDS)
+SCOPES = (SNAPSHOT_SCOPE, INNER_STEP_SCOPE, READ_SCOPE, *READER_SCOPES,
+          SAMPLE_GRAD_SCOPE, DROP_MASK_SCOPE, svrg_update_ops.SCOPE,
+          LOSS_SCOPE)
+
 
 class AsyRunResult(NamedTuple):
     w: jnp.ndarray
@@ -144,11 +160,15 @@ def read_dispatch(scheme_id, buffer, tau, a, m, key, dim: int):
     def slot_of(age):
         return jnp.mod(age, buf_len)
 
-    branches = [
-        (lambda ops, r=reader: r(ops[0], slot_of, ops[1], ops[2], ops[3], dim))
-        for reader in _READER_LIST
-    ]
-    return jax.lax.switch(scheme_id, branches, (buffer, a, m, key))
+    def branch(reader, scope):
+        def read(ops):
+            with jax.named_scope(scope):
+                return reader(ops[0], slot_of, ops[1], ops[2], ops[3], dim)
+        return read
+
+    branches = [branch(r, s) for r, s in zip(_READER_LIST, READER_SCOPES)]
+    with jax.named_scope(READ_SCOPE):
+        return jax.lax.switch(scheme_id, branches, (buffer, a, m, key))
 
 
 def _epoch_core(obj: Objective, data, w, key, eta, tau, scheme_id, delay_id,
@@ -171,7 +191,8 @@ def _epoch_core(obj: Objective, data, w, key, eta, tau, scheme_id, delay_id,
     n = obj.num_samples(data)
     dim = w.shape[0]
     k_idx, k_delay, k_scan = jax.random.split(key, 3)
-    mu = obj.flat_full_grad(data, w)                    # parallel snapshot pass
+    with jax.named_scope(SNAPSHOT_SCOPE):
+        mu = obj.flat_full_grad(data, w)                # parallel snapshot pass
     u0 = w
     idx = jax.random.randint(k_idx, (total,), 0, n)
     delays = _delay_schedule_core(delay_id, total, tau, k_delay)
@@ -179,26 +200,31 @@ def _epoch_core(obj: Objective, data, w, key, eta, tau, scheme_id, delay_id,
     buffer = jnp.tile(u0[None, :], (buf_len, 1))        # slot m%(τ+1) = u_m
 
     def body(carry, inp):
-        u, buffer, acc = carry
-        m, i, d, k = inp
-        k_read, k_drop = jax.random.split(k)
-        a = jnp.maximum(m - d, 0)
-        u_read = read_dispatch(scheme_id, buffer, tau, a, m, k_read, dim)
-        g = obj.flat_sample_grad(data, i, u_read)
-        g0 = obj.flat_sample_grad(data, i, u0)
-        gf = mu
-        if drop_prob > 0:
-            # unlock write-write race: drop a random coordinate fraction.
-            # Masking the three inputs with the same 0/1 mask equals masking
-            # v = g − g0 + gf (exact for 0/1 factors), which keeps the update
-            # expressible as the fused kernel's 4-read form.
-            keep = jax.random.bernoulli(
-                k_drop, 1.0 - drop_prob, (dim,)).astype(u.dtype)
-            mask = jnp.where(scheme_id == _UNLOCK, keep, jnp.ones_like(keep))
-            g, g0, gf = g * mask, g0 * mask, gf * mask
-        u_next = svrg_update_ops.apply_leaf(u, g, g0, gf, eta)
-        buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
-        return (u_next, buffer, acc + u_next), None
+        with jax.named_scope(INNER_STEP_SCOPE):
+            u, buffer, acc = carry
+            m, i, d, k = inp
+            k_read, k_drop = jax.random.split(k)
+            a = jnp.maximum(m - d, 0)
+            u_read = read_dispatch(scheme_id, buffer, tau, a, m, k_read, dim)
+            with jax.named_scope(SAMPLE_GRAD_SCOPE):
+                g = obj.flat_sample_grad(data, i, u_read)
+                g0 = obj.flat_sample_grad(data, i, u0)
+            gf = mu
+            if drop_prob > 0:
+                # unlock write-write race: drop a random coordinate
+                # fraction. Masking the three inputs with the same 0/1 mask
+                # equals masking v = g − g0 + gf (exact for 0/1 factors),
+                # which keeps the update expressible as the fused kernel's
+                # 4-read form.
+                with jax.named_scope(DROP_MASK_SCOPE):
+                    keep = jax.random.bernoulli(
+                        k_drop, 1.0 - drop_prob, (dim,)).astype(u.dtype)
+                    mask = jnp.where(scheme_id == _UNLOCK, keep,
+                                     jnp.ones_like(keep))
+                    g, g0, gf = g * mask, g0 * mask, gf * mask
+            u_next = svrg_update_ops.apply_leaf(u, g, g0, gf, eta)
+            buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
+            return (u_next, buffer, acc + u_next), None
 
     keys = jax.random.split(k_scan, total)
     ms = jnp.arange(total)
@@ -226,7 +252,8 @@ def _asysvrg_epochs_core(obj: Objective, data, w0, key, eta, tau, scheme_id,
     (`repro.kernels.sweep_epoch`) — both paths execute literally this
     function, which is what makes them bit-identical on XLA:CPU.
     """
-    loss0 = obj.flat_loss(data, w0)
+    with jax.named_scope(LOSS_SCOPE):
+        loss0 = obj.flat_loss(data, w0)
     bound = jnp.int32(epochs) if row_epochs is None else row_epochs
 
     def step(carry, e):
@@ -241,8 +268,9 @@ def _asysvrg_epochs_core(obj: Objective, data, w0, key, eta, tau, scheme_id,
         # live loss is re-emitted), so a row with a shorter budget is
         # bit-identical to an independent shorter run
         w_next = jnp.where(active, w_new, w)
-        loss_next = jnp.where(active, obj.flat_loss(data, w_next),
-                              loss_prev)
+        with jax.named_scope(LOSS_SCOPE):
+            loss_w = obj.flat_loss(data, w_next)
+        loss_next = jnp.where(active, loss_w, loss_prev)
         return (w_next, key, loss_next), loss_next
 
     (w_fin, _, _), losses = jax.lax.scan(

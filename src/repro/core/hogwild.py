@@ -28,6 +28,10 @@ from repro.core.asysvrg import (
     _UNLOCK,
     AsyRunResult,
     DELAY_IDS,
+    DROP_MASK_SCOPE,
+    INNER_STEP_SCOPE,
+    LOSS_SCOPE,
+    SAMPLE_GRAD_SCOPE,
     SCHEME_IDS,
     _delay_schedule_core,
     read_dispatch,
@@ -66,21 +70,25 @@ def _hogwild_epoch_core(obj: Objective, data, w, key, gamma, tau, scheme_id,
     buffer = jnp.tile(w[None, :], (buf_len, 1))         # slot m%(τ+1) = u_m
 
     def body(carry, inp):
-        u, buffer = carry
-        m, i, d, k = inp
-        k_read, k_drop = jax.random.split(k)
-        a = jnp.maximum(m - d, 0)
-        u_read = read_dispatch(scheme_id, buffer, tau, a, m, k_read, dim)
-        v = obj.flat_sample_grad(data, i, u_read)
-        if drop_prob > 0:
-            # unlock write-write race: drop a random coordinate fraction
-            keep = jax.random.bernoulli(
-                k_drop, 1.0 - drop_prob, (dim,)).astype(u.dtype)
-            mask = jnp.where(scheme_id == _UNLOCK, keep, jnp.ones_like(keep))
-            v = v * mask
-        u_next = u - gamma * v
-        buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
-        return (u_next, buffer), None
+        with jax.named_scope(INNER_STEP_SCOPE):
+            u, buffer = carry
+            m, i, d, k = inp
+            k_read, k_drop = jax.random.split(k)
+            a = jnp.maximum(m - d, 0)
+            u_read = read_dispatch(scheme_id, buffer, tau, a, m, k_read, dim)
+            with jax.named_scope(SAMPLE_GRAD_SCOPE):
+                v = obj.flat_sample_grad(data, i, u_read)
+            if drop_prob > 0:
+                # unlock write-write race: drop a random coordinate fraction
+                with jax.named_scope(DROP_MASK_SCOPE):
+                    keep = jax.random.bernoulli(
+                        k_drop, 1.0 - drop_prob, (dim,)).astype(u.dtype)
+                    mask = jnp.where(scheme_id == _UNLOCK, keep,
+                                     jnp.ones_like(keep))
+                    v = v * mask
+            u_next = u - gamma * v
+            buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
+            return (u_next, buffer), None
 
     keys = jax.random.split(k_scan, total)
     ms = jnp.arange(total)
@@ -105,7 +113,8 @@ def _hogwild_epochs_core(obj: Objective, data, w0, key, gamma0, decay, tau,
     shorter budget is bit-identical to an independent shorter run while
     scanning to the group's shared static bound.
     """
-    loss0 = obj.flat_loss(data, w0)
+    with jax.named_scope(LOSS_SCOPE):
+        loss0 = obj.flat_loss(data, w0)
     bound = jnp.int32(epochs) if row_epochs is None else row_epochs
 
     def step(carry, e):
@@ -117,8 +126,9 @@ def _hogwild_epochs_core(obj: Objective, data, w0, key, gamma0, decay, tau,
             total=total, buf_len=buf_len, drop_prob=drop_prob)
         w_next = jnp.where(active, w_new, w)
         gamma_next = jnp.where(active, gamma * decay, gamma)
-        loss_next = jnp.where(active, obj.flat_loss(data, w_next),
-                              loss_prev)
+        with jax.named_scope(LOSS_SCOPE):
+            loss_w = obj.flat_loss(data, w_next)
+        loss_next = jnp.where(active, loss_w, loss_prev)
         return (w_next, key, gamma_next, loss_next), loss_next
 
     (w_fin, _, _, _), losses = jax.lax.scan(

@@ -84,7 +84,6 @@ many clients' specs through the same runners.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -104,7 +103,6 @@ from repro.core.asysvrg import (
 from repro.core.hogwild import _hogwild_epochs_core, _resolve_hogwild_steps
 from repro.core.objective import Objective, get_objective, params_from_flat
 from repro.kernels.dispatch import fused_sweep_mode
-from repro.obs import ledger as _ledger
 from repro.obs.trace import tracer as _tracer
 from repro.sharding.context import current_mesh
 
@@ -669,6 +667,9 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
         args = _pad_rows(args, -len(members) % int(mesh.shape[_DATA_AXIS]))
     # the execute span brackets the runner CALL (dispatch + any trace-time
     # compile), never code inside the jit — RL006 enforces that boundary.
+    # With tracing on it also waits for the device, so it ends when the
+    # outputs are ready, and its `fetch` child times the copies to the
+    # host; off, the call returns at the enqueue and `np.asarray` waits.
     # Tag construction is gated so the tracer-off warm path pays only the
     # enabled check; compiled=True lands via cache._counted's annotate.
     tr = _tracer()
@@ -677,40 +678,17 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
         from repro.kernels.dispatch import mode_tags
         tags = dict(engine=engine, rows=len(members), total=int(total),
                     group_epochs=int(group_epochs), **mode_tags(fused))
-    # The performance ledger (opt-in, one-bool check) times the same
-    # bracket the execute span does — wall clock around the runner CALL,
-    # host-side, never inside the compiled body (RL006).
-    led_on = _ledger.ledger_enabled()
-    t0 = time.perf_counter() if led_on else 0.0
     with tr.span_active("execute", **tags):
         w_fin, hist = runner(*obj.data_args(), *args)
-    if led_on:
-        call_args = (*obj.data_args(), *args)
-        _ledger.ledger().record_dispatch(
-            key=key_, rows=int(args[-1].shape[0]), dim=int(w_init.shape[0]),
-            epochs=int(group_epochs), wall_s=time.perf_counter() - t0,
-            cost_fn=lambda: _aot_cost_analysis(runner, call_args))
-    return (np.asarray(hist)[:len(members)],
-            np.asarray(w_fin)[:len(members)])
-
-
-def _aot_cost_analysis(runner, call_args):
-    """XLA's own FLOPs/bytes estimate for one cached group runner, via the
-    AOT path. The re-trace this forces is bookkeeping, not a user-visible
-    (re)compile — `uncounted_trace` keeps it out of the compile counters
-    the warm-path contracts (0 recompiles) are pinned on."""
-    from repro.service.cache import uncounted_trace
-
-    with uncounted_trace():
-        cost = runner.lower(*call_args).compile().cost_analysis()
-    # jax returns either one dict or a per-device list of dicts
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return cost
+        if tr.enabled:
+            jax.block_until_ready((w_fin, hist))
+        with tr.span_active("fetch"):
+            return (np.asarray(hist)[:len(members)],
+                    np.asarray(w_fin)[:len(members)])
 
 
 def group_label(key_: _GroupKey) -> str:
-    """Human-readable label for one compiled group (progress/ledger ids)."""
+    """Human-readable label for one compiled group (progress ids)."""
     _, engine, total, option, buf_len, fused = key_
     return (f"{engine}-{'fused' if fused else 'vmap'}-M{int(total)}"
             f"-opt{option}-buf{int(buf_len)}")

@@ -46,4 +46,5 @@ def svrg_update_2d(u, g, g0, gf, lr, wd: float = 0.0,
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         interpret=interpret,
+        name="svrg_update",
     )(lr, u, g, g0, gf)

@@ -15,25 +15,30 @@ from repro.kernels.svrg_update.kernel import (
     BLOCK_ROWS, LANES, svrg_update_2d)
 from repro.kernels.svrg_update.ref import svrg_update_ref
 
+# the update's name in op metadata and the kernel's name (a device trace's
+# `tf_op` path carries it; see repro.core.asysvrg.SCOPES)
+SCOPE = "svrg_update"
+
 
 def apply_leaf(u, g, g0, gf, lr, wd: float = 0.0, interpret: bool = False,
                force_kernel: bool = False):
     mode = kernel_mode(interpret, force_kernel)
-    if mode == "reference":
-        return svrg_update_ref(u, g, g0, gf, lr, wd)
-    interpret = mode == "interpret"
-    n = u.size
-    tile = BLOCK_ROWS * LANES
-    rows = -(-n // tile) * BLOCK_ROWS
-    pad = rows * LANES - n
+    with jax.named_scope(SCOPE):
+        if mode == "reference":
+            return svrg_update_ref(u, g, g0, gf, lr, wd)
+        interpret = mode == "interpret"
+        n = u.size
+        tile = BLOCK_ROWS * LANES
+        rows = -(-n // tile) * BLOCK_ROWS
+        pad = rows * LANES - n
 
-    def prep(x):
-        return jnp.pad(x.reshape(-1), (0, pad)).reshape(rows, LANES)
+        def prep(x):
+            return jnp.pad(x.reshape(-1), (0, pad)).reshape(rows, LANES)
 
-    lr_arr = jnp.full((1, 1), lr, jnp.float32)
-    out = svrg_update_2d(prep(u), prep(g), prep(g0), prep(gf), lr_arr,
-                         wd=wd, interpret=interpret)
-    return out.reshape(-1)[:n].reshape(u.shape)
+        lr_arr = jnp.full((1, 1), lr, jnp.float32)
+        out = svrg_update_2d(prep(u), prep(g), prep(g0), prep(gf), lr_arr,
+                             wd=wd, interpret=interpret)
+        return out.reshape(-1)[:n].reshape(u.shape)
 
 
 def apply_tree(params, g, g0, gf, lr, wd: float = 0.0,

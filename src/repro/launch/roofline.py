@@ -424,9 +424,8 @@ def attained_fraction(*, rows: int, dim: int, total: int, epochs: int,
 
     Selects the engine path (vmap or fused megakernel) of
     :func:`sweep_epoch_roofline` and divides its step lower bound by the
-    measured wall time — the per-group "how close to the hardware are
-    we" number the performance ledger (``repro.obs.ledger``) records and
-    the multi-host fabric will route on. On a backend other than ``hw``
+    measured wall time — a per-group "how close to the hardware are
+    we" number. On a backend other than ``hw``
     (e.g. the CPU CI container vs the TPU_V5E default) the fraction is a
     cross-hardware comparison, not a utilization: still monotone in
     dispatch speed, so regressions show, but only meaningful in absolute

@@ -18,9 +18,8 @@ Four stdlib-only pieces plus two numeric ones:
     already-returned arrays (imports jax; import it explicitly, never
     from this package root, so the tracer stays importable in the
     stdlib-only repro-lint lane).
-  * `repro.obs.watchdog` / `repro.obs.ledger` — divergence watchdog and
-    per-group performance ledger (import numpy / the roofline model;
-    import them explicitly for the same reason as telemetry).
+  * `repro.obs.watchdog` — divergence watchdog (imports numpy; import it
+    explicitly for the same reason as telemetry).
 
 House rule (repro-lint RL006): none of these APIs may be called inside a
 ``*_core`` jitted scope or a ``kernels/**/kernel.py`` module —

@@ -31,9 +31,7 @@ from typing import Dict, List, Mapping, Optional
 
 _NAME_OK = re.compile(r"[^a-zA-Z0-9_:]")
 # snapshot subtrees whose keys are arbitrary identifiers -> label name
-# ("ledger" keys are per-group runner labels -> repro_ledger_* series;
-# its string leaves like flops_source are skipped by _format_value)
-_LABELED = {"tenants": "tenant", "deficits": "tenant", "ledger": "group"}
+_LABELED = {"tenants": "tenant", "deficits": "tenant"}
 
 
 def _metric_name(prefix: str, parts: List[str]) -> str:

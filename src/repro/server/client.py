@@ -229,11 +229,6 @@ class SweepClient:
                 continue                 # still slicing: poll again
             return result_from_dict(payload)
 
-    def ledger(self) -> dict:
-        """The per-group performance ledger (``GET /ledger``):
-        ``{"enabled": bool, "groups": {label: entry-dict}}``."""
-        return self._call("GET", "/ledger")
-
     def sweep(self, specs: Sequence[SweepSpec],
               epochs: Optional[int] = None, *, tenant: str = "default",
               priority: int = 0,

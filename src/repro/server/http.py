@@ -38,11 +38,6 @@ daemon below it are already thread-safe) exposing the serving tier:
     GET  /job/N?timeout_s=S
                     -> the finished job's SweepResult (504 pending while
                     slices still run — watch /watch?id=job-N meanwhile)
-    GET  /ledger    -> {"enabled": bool, "groups": {...}} — the per-group
-                    performance ledger (`repro.obs.ledger`): compile
-                    time, FLOPs/bytes, attained-vs-roofline fraction per
-                    compiled group runner (all zeros/empty until
-                    ``enable_ledger()``)
 
 Status mapping: bad input 400; unknown id 404; completed-but-evicted id
 410 (`ResultEvictedError` — re-submit or raise ``max_results``); result
@@ -67,7 +62,6 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 from repro.core.sweep import SweepResult, SweepSpec
-from repro.obs import ledger as _ledger
 from repro.obs import progress as _progress
 from repro.obs import prometheus as _prometheus
 from repro.obs import telemetry as _obs_telemetry
@@ -191,9 +185,6 @@ class _Handler(BaseHTTPRequestHandler):
                 self._get_healthz()
             elif url.path == "/watch":
                 self._get_watch(url.query)
-            elif url.path == "/ledger":
-                self._json(200, {"enabled": _ledger.ledger_enabled(),
-                                 "groups": _ledger.ledger().snapshot()})
             elif url.path == "/stats":
                 self._json(200, _metrics.snapshot(
                     self.svc, self.server.daemon, self.server.fairness))
@@ -319,8 +310,10 @@ class _Handler(BaseHTTPRequestHandler):
             return self._error(404, f"unknown request id {rid}",
                                status="unknown")
         tid = self.svc.trace_id(rid)
-        self._json(200, result_to_dict(rid, res),
-                   {"X-Trace-Id": tid} if tid else None)
+        # the HTTP tier's own share of the answer: JSON and the socket write
+        with _tracer().span(tid, "encode", parent_name="submit"):
+            self._json(200, result_to_dict(rid, res),
+                       {"X-Trace-Id": tid} if tid else None)
 
     def do_POST(self) -> None:         # noqa: N802 (stdlib handler API)
         url = urlparse(self.path)

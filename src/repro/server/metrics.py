@@ -16,7 +16,6 @@ from typing import Dict, Optional, Sequence
 
 import numpy as np
 
-from repro.obs import ledger as _ledger
 from repro.server.daemon import ServeDaemon
 from repro.server.fairness import FairShare
 from repro.service import cache as _cache
@@ -77,8 +76,4 @@ def snapshot(service: SweepService, daemon: Optional[ServeDaemon] = None,
             "max_rows_per_flush": fairness.max_rows_per_flush,
             "deficits": fairness.deficits(),
         }
-    if _ledger.ledger_enabled():
-        # opt-in section: tests pin the exact default section set, and an
-        # empty ledger on every scrape would just be noise
-        out["ledger"] = _ledger.ledger().snapshot()
     return out

@@ -42,7 +42,6 @@ from jax.sharding import Mesh
 
 from repro.core import sweep as _sweep
 from repro.kernels.dispatch import fused_sweep_mode, kernel_mode
-from repro.obs import ledger as _ledger
 from repro.obs.trace import tracer as _tracer
 from repro.sharding.context import mesh_fingerprint
 
@@ -103,10 +102,10 @@ def scoped_counters(sink: _Counters):
 @contextlib.contextmanager
 def uncounted_trace():
     """Suspend compile counting on this thread: a re-trace forced for
-    bookkeeping (the ledger's one-time AOT ``cost_analysis`` of an
-    already-compiled runner) is not a user-visible (re)compile, and must
-    not perturb the exact-compile-count contracts (`tests/test_service.py`,
-    the obs-smoke 0-recompiles gate)."""
+    bookkeeping (an AOT lowering of an already-compiled runner, as
+    `chip_smoke.py` makes to look into its program) is not a user-visible
+    (re)compile, and must not perturb the exact-compile-count contracts
+    (`tests/test_service.py`, the obs-smoke 0-recompiles gate)."""
     prev = getattr(_TLS, "uncounted", False)
     _TLS.uncounted = True
     try:
@@ -180,9 +179,6 @@ def _counted(fn):
         # dispatch/execute span group (if any) gets the attribution; the
         # tracer's lock is a leaf, so holding no cache lock here matters
         _tracer().annotate(compiled=True)
-        # same-thread hook: lets the performance ledger attribute the
-        # wall time of the dispatch in flight to compilation
-        _ledger.note_compile()
         return fn(*args)
     return traced
 

@@ -349,22 +349,25 @@ def sweep_core(w, hist):
     bus = progress_bus()
     bus.publish(kind="slice")
     enforce_group(wd, hist, w)
-    led = ledger()
-    led.record_dispatch(key=k)
+    with jax.named_scope("inner_step"):
+        w = w + 1
     return w
 """
 
 
 def test_rl006_flags_progress_watchdog_ledger_inside_core_scopes():
-    """PR-10 surface: the live-progress bus, divergence watchdog and perf
-    ledger are host-side by contract — any call inside a jitted scope is
-    flagged, same as the tracer API."""
+    """The live-progress bus and the divergence watchdog are host-side by
+    contract — any call inside a jitted scope is flagged, same as the
+    tracer API. `jax.named_scope` only names the ops in their metadata,
+    so it is allowed there (the performance ledger is gone, and its
+    names are no findings any more)."""
     diags = lint_source(BAD_RL006_LIVE_OBS)
-    assert codes(diags) == ["RL006"] * 5
-    assert [d.line for d in diags] == [2, 3, 4, 5, 6]
+    assert codes(diags) == ["RL006"] * 3
+    assert [d.line for d in diags] == [2, 3, 4]
     assert any("progress-bus" in d.message for d in diags)
     assert any("watchdog" in d.message for d in diags)
-    assert any("ledger" in d.message for d in diags)
+    assert lint_source("def sweep_core(w):\n    led = ledger()\n"
+                       "    return w\n") == []
     # the identical calls outside *_core scopes are exactly where they
     # belong (dispatch sites, services, HTTP handlers)
     assert lint_source(BAD_RL006_LIVE_OBS.replace(

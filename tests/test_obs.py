@@ -125,8 +125,10 @@ def test_service_records_complete_span_chain(obj):
         names = [s["name"] for s in dump["spans"]]
         # no width policy on a bare service -> no pad span
         assert set(names) == {"submit", "plan", "coalesce", "dispatch",
-                              "execute", "demux", "result"}
+                              "execute", "fetch", "demux", "result"}
         by_name = {s["name"]: s for s in dump["spans"]}
+        assert (by_name["fetch"]["parent_id"]
+                == by_name["execute"]["span_id"])
         assert by_name["submit"]["tags"]["tenant"] == "team-a"
         assert by_name["submit"]["tags"]["request_id"] == rid
         assert by_name["dispatch"]["tags"]["cache"] in ("hit", "miss")
@@ -307,12 +309,36 @@ def test_http_request_has_complete_retrievable_span_tree(traced_server, obj):
     names = {s["name"] for s in dump["spans"]}
     # the daemon installs a width policy, so the pad phase appears too
     assert names == {"submit", "plan", "coalesce", "pad", "dispatch",
-                     "execute", "demux", "result"}
+                     "execute", "fetch", "demux", "result", "encode"}
     recent = client.trace()
     assert recent["enabled"] is True
     assert header_tid in [t["trace_id"] for t in recent["recent"]]
     with pytest.raises(Exception):          # unknown id -> 404
         client.trace("t-nope")
+
+
+def test_http_result_has_fetch_under_execute_and_encode_after_result(
+        traced_server, obj):
+    """The device wait and the host copy of a dispatch are separate spans
+    (`fetch` under `execute`), and the HTTP tier's answer is its own span
+    (`encode`, under `submit`) after the last `result` wait."""
+    svc, server, client = traced_server
+    rid = client.submit(_specs([4]), tenant="team-c")
+    client.result(rid, timeout=30)
+    spans = client.trace(svc.trace_id(rid))["spans"]
+    by_id = {s["span_id"]: s for s in spans}
+    fetch = [s for s in spans if s["name"] == "fetch"]
+    assert fetch and all(by_id[s["parent_id"]]["name"] == "execute"
+                         for s in fetch)
+    execute = by_id[fetch[0]["parent_id"]]
+    assert execute["start_s"] <= fetch[0]["start_s"]
+    encode = [s for s in spans if s["name"] == "encode"]
+    assert len(encode) == 1
+    assert by_id[encode[0]["parent_id"]]["name"] == "submit"
+    last_result = max((s for s in spans if s["name"] == "result"),
+                      key=lambda s: s["start_s"])
+    assert encode[0]["start_s"] >= (last_result["start_s"]
+                                    + last_result["duration_ms"] / 1000.0)
 
 
 def test_http_metrics_endpoint_is_valid_prometheus(traced_server, obj):

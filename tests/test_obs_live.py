@@ -1,4 +1,4 @@
-"""Live observability suite: progress streaming, watchdog, perf ledger.
+"""Live observability suite: progress streaming and the watchdog.
 
 The PR-10 contracts, bottom-up:
 
@@ -14,9 +14,6 @@ The PR-10 contracts, bottom-up:
   * Progress events — per-slice/-flush loss series equal the final
     `SweepResult` histories bit-for-bit, and watchdog truncations
     persist across checkpoint-resume.
-  * `PerfLedger` — per-group compile/warm attribution with exact compile
-    counting (AOT ``cost_analysis`` must not inflate the cache's compile
-    counters) and roofline-based attained fraction.
   * End-to-end acceptance: a multi-slice job submitted over HTTP,
     streamed live via ``GET /watch`` while it runs.
 
@@ -51,14 +48,11 @@ def obj():
 
 @pytest.fixture(autouse=True)
 def _obs_off():
-    """Every test starts and ends with the live-obs toggles off and the
-    process-global bus/ledger empty (they are process singletons)."""
-    from repro.obs.ledger import disable_ledger
+    """Every test starts and ends with the live-obs toggle off and the
+    process-global bus empty (it is a process singleton)."""
     disable_progress(clear=True)
-    disable_ledger(clear=True)
     yield
     disable_progress(clear=True)
-    disable_ledger(clear=True)
 
 
 def _specs(seeds, step_size=0.5, inner_steps=25):
@@ -331,46 +325,6 @@ def test_run_job_slice_events_and_watchdog_resume(obj, tmp_path):
                                       ref.histories[ref_row])
         np.testing.assert_array_equal(res.final_w[row],
                                       ref.final_w[ref_row])
-
-
-# -------------------------------------------------------------------- ledger
-def test_ledger_per_group_attribution(obj):
-    """One cold + one warm dispatch of a fresh group: dispatches=2,
-    compiles=1 with compile_s attributed, a warm floor, FLOPs (XLA
-    cost_analysis or the analytic fallback — named either way) and an
-    attained-vs-roofline fraction. The AOT cost_analysis retrace must not
-    inflate the runner cache's exact compile counters."""
-    from repro.obs.ledger import disable_ledger, enable_ledger
-    specs = _specs([0, 1], inner_steps=27)        # unique group: cold here
-    led = enable_ledger()
-    led.clear()
-    svc = SweepService(obj, epochs=2)
-    base = cache_stats()
-    for _ in range(2):
-        rid = svc.submit(specs)
-        svc.flush()
-        svc.result(rid)
-    assert cache_stats().since(base).compiles == 1, \
-        "cost_analysis retrace leaked into the counted compile path"
-
-    snap = led.snapshot()
-    assert len(snap) == 1
-    label, entry = next(iter(snap.items()))
-    assert label.startswith("asysvrg-vmap-") and "-rows2-E2" in label
-    assert entry["dispatches"] == 2 and entry["compiles"] == 1
-    assert entry["compile_s"] > 0.0
-    assert 0.0 < entry["warm_wall_min_s"] < entry["compile_s"]
-    assert entry["flops"] > 0.0 and entry["bytes"] > 0.0
-    assert entry["flops_source"] in ("cost_analysis", "analytic")
-    assert entry["roofline_s"] > 0.0 and entry["attained_frac"] > 0.0
-
-    disable_ledger(clear=True)
-    base = cache_stats()
-    rid = svc.submit(specs)
-    svc.flush()
-    svc.result(rid)                               # off: nothing recorded
-    assert len(led.snapshot()) == 0
-    assert cache_stats().since(base).compiles == 0
 
 
 # ------------------------------------------------------- end-to-end over HTTP

@@ -132,32 +132,3 @@ def test_prometheus_escapes_malicious_tenant_labels(obj):
         r"^(#.*|[a-zA-Z_:][a-zA-Z0-9_:]*(\{.*\})?\s[^\s]+)$")
     bad = [ln for ln in text.splitlines() if ln and not prom_line.match(ln)]
     assert not bad, bad
-
-
-def test_snapshot_ledger_section_is_opt_in(obj):
-    """The exact default section set (pinned above) must not grow when
-    the ledger is off; enabling it adds one ``ledger`` section whose
-    groups render as ``repro_ledger_*{group=...}`` series."""
-    from repro.obs.ledger import disable_ledger, enable_ledger
-    from repro.obs.prometheus import render
-    svc = _worked_service(obj)
-    assert "ledger" not in snapshot(svc)
-    enable_ledger().clear()
-    try:
-        svc.submit(_specs([9]))
-        svc.flush()
-        snap = snapshot(svc)
-        assert set(snap) == {"service", "queue", "tenants", "flush_latency",
-                             "request_latency", "runner_cache", "ledger"}
-        _assert_builtin_tree(snap)
-        assert json.loads(json.dumps(snap)) == snap
-        assert len(snap["ledger"]) >= 1
-        entry = next(iter(snap["ledger"].values()))
-        assert {"dispatches", "compile_s", "flops",
-                "attained_frac"} <= set(entry)
-        text = render(snap)
-        assert 'repro_ledger_dispatches{group="' in text
-        assert 'repro_ledger_attained_frac{group="' in text
-    finally:
-        disable_ledger(clear=True)
-    assert "ledger" not in snapshot(svc)

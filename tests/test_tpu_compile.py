@@ -12,6 +12,7 @@ runner cache, and the backend the kernel dispatch sees is steered with
 ``monkeypatch``: JAX itself still runs on the CPU.
 """
 import os
+import re
 import types
 
 import jax
@@ -114,6 +115,23 @@ def test_vmap_asysvrg_runner_compiles_with_kernel(topo, tpu_backend):
     compiled = _compile("asysvrg", one_chip, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_compiled_kernel_call_carries_its_scopes(topo, tpu_backend):
+    """A device trace names each op by its compiled instruction and keeps
+    its name stack (``tf_op``): the kernel's call is the `svrg_update`
+    Pallas call inside the inner step, so a trace reader can count steps
+    by it."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    hlo = _compile("asysvrg", one_chip, one_chip).as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    op_name = re.search(r'op_name="([^"]*)"', calls[0]).group(1)
+    parts = op_name.split("/")
+    assert parts[-1] == "pallas_call"
+    assert parts.index("inner_step") < parts.index("svrg_update")
+    assert calls[0].lstrip().startswith("%svrg_update")
 
 
 def test_hogwild_runner_compiles(topo, tpu_backend):
