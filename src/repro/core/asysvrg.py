@@ -136,7 +136,10 @@ def _read_unlock(buffer, slot_of, a, m, key, dim):
     span = (m - a + 1).astype(jnp.float32)
     ages = a + jnp.floor(jax.random.uniform(key, (dim,)) * span).astype(jnp.int32)
     slots = slot_of(ages)
-    return buffer[slots, jnp.arange(dim)]
+    # Every slot is below τ + 1 ≤ the buffer's static length, so a select
+    # over its rows returns exactly the bits of the per-coordinate gather
+    # `buffer[slots, arange(dim)]`, which a TPU runs far slower.
+    return jax.lax.select_n(slots, *buffer)
 
 
 _READERS = {
@@ -154,7 +157,16 @@ def read_dispatch(scheme_id, buffer, tau, a, m, key, dim: int):
     ``scheme_id``/``tau`` may be traced (one trace serves every scheme in a
     sweep batch); ``dim`` is static. The ring-buffer slot arithmetic uses the
     DYNAMIC τ, so a buffer padded to any length ≥ τ+1 reads identically.
+    A buffer of exactly one slot (τ = 0 and one thread: `run_asysvrg` at
+    τ = 0, or a sweep row with ``num_threads=1``; `_row_buf_len` pads other
+    τ = 0 rows to their thread count) holds the only iterate any scheme can
+    read, so it is returned with no dispatch. That keeps such a sweep row
+    bit-identical to `run_asysvrg` on XLA:CPU, whose fused code otherwise
+    differs between the two programs by an ulp.
     """
+    if buffer.shape[0] == 1:
+        with jax.named_scope(READ_SCOPE):
+            return buffer[0]
     buf_len = tau + 1
 
     def slot_of(age):

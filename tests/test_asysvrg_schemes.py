@@ -13,7 +13,8 @@ except ImportError:
 from repro.config import SVRGConfig
 from repro.core import LogisticRegression, make_delay_schedule, run_asysvrg
 from repro.core.asysvrg import (
-    _read_consistent, _read_inconsistent, _read_unlock)
+    SCHEME_IDS, _read_consistent, _read_inconsistent, _read_unlock,
+    read_dispatch)
 from repro.data.libsvm import make_synthetic_libsvm
 
 
@@ -87,6 +88,105 @@ def test_unlock_read_spans_full_window():
         jax.random.PRNGKey(2), dim))
     ages = set(np.unique(got))
     assert ages == {0.0, 1.0, 2.0, 3.0, 4.0}
+
+
+def _gather_unlock(buffer, tau, a, m, key, dim):
+    """The unlock read as a per-coordinate gather: the same ages from the
+    same key, then ``buffer[slots, arange(dim)]``."""
+    span = (m - a + 1).astype(jnp.float32)
+    ages = a + jnp.floor(jax.random.uniform(key, (dim,)) * span).astype(jnp.int32)
+    return buffer[jnp.mod(ages, tau + 1), jnp.arange(dim)]
+
+
+def _reference_read(scheme, buffer, tau, a, m, key, dim):
+    """What a scheme reads: the locked readers themselves, the unlock read
+    as the gather."""
+    if scheme == "unlock":
+        return _gather_unlock(buffer, tau, a, m, key, dim)
+    reader = (_read_consistent if scheme == "consistent"
+              else _read_inconsistent)
+    return reader(buffer, lambda age: jnp.mod(age, tau + 1), a, m, key, dim)
+
+
+def _signed_buffer(buf_len, dim, seed):
+    """Distinct values in every slot, with −0.0 and +0.0 in whole columns:
+    a read that took a wrong slot, or summed where it should select,
+    changes bits."""
+    buf = np.random.default_rng(seed).standard_normal(
+        (buf_len, dim)).astype(np.float32)
+    buf[:, 0::7] = -0.0
+    buf[:, 3::7] = 0.0
+    return jnp.asarray(buf)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("tau,buf_len,signed_zeros", [
+    (0, 1, False), (4, 5, False), (9, 10, False),
+    (4, 16, False),                      # padded beyond τ + 1
+    (9, 10, True),                       # −0.0 and +0.0 entries
+    (299, 300, True),                    # a ring of 300 slots
+], ids=["tau0", "tau4", "tau9", "tau4_padded16", "negative_zero",
+        "tau299_long_ring"])
+def test_unlock_read_equals_gather_bitwise(tau, buf_len, signed_zeros):
+    """The unlock read returns, bit for bit, what a per-coordinate gather
+    from the ring buffer returns with the same key."""
+    dim, m = 1000, 2 * buf_len + 3           # the ages wrap round the ring
+    buf = (_signed_buffer(buf_len, dim, tau) if signed_zeros else
+           jnp.asarray(np.random.default_rng(tau).standard_normal(
+               (buf_len, dim)), jnp.float32))
+    tau_, m_ = jnp.asarray(tau), jnp.asarray(m)
+    a = jnp.maximum(m_ - tau_, 0)
+    key = jax.random.PRNGKey(7)
+    got = _read_unlock(buf, lambda age: jnp.mod(age, tau_ + 1), a, m_, key,
+                       dim)
+    want = _gather_unlock(buf, tau_, a, m_, key, dim)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    if signed_zeros:
+        assert np.signbit(np.asarray(got)[0::7]).all()
+        assert not np.signbit(np.asarray(got)[3::7]).any()
+
+
+def test_unlock_read_equals_gather_bitwise_vmap_mixed_schemes():
+    """Under `vmap` over rows of mixed schemes and τ (as the sweep runs
+    them), `read_dispatch` gives each unlock row the gather's bits and the
+    locked rows their own readers' bits."""
+    dim, buf_len = 1000, 10
+    schemes = ["consistent", "inconsistent", "unlock", "unlock",
+               "inconsistent", "unlock"]
+    taus = jnp.asarray([9, 4, 9, 0, 9, 4])
+    ms = jnp.asarray([31, 12, 17, 5, 3, 40])
+    ids = jnp.asarray([SCHEME_IDS[s] for s in schemes])
+    a = jnp.maximum(ms - taus, 0)
+    keys = jax.random.split(jax.random.PRNGKey(11), len(schemes))
+    bufs = jnp.stack([_signed_buffer(buf_len, dim, r)
+                      for r in range(len(schemes))])
+
+    got = jax.jit(jax.vmap(
+        lambda s, b, t, a_, m, k: read_dispatch(s, b, t, a_, m, k, dim)))(
+            ids, bufs, taus, a, ms, keys)
+    for r, scheme in enumerate(schemes):
+        want = _reference_read(scheme, bufs[r], taus[r], a[r], ms[r], keys[r],
+                               dim)
+        np.testing.assert_array_equal(_bits(got[r]), _bits(want),
+                                      err_msg=f"row {r} ({scheme})")
+
+
+@pytest.mark.parametrize("scheme", list(SCHEME_IDS))
+def test_single_slot_read_is_the_one_iterate(scheme):
+    """At τ = 0 the buffer holds one iterate: `read_dispatch` returns it,
+    bit for bit what the scheme's own reader returns."""
+    dim, m = 1000, 6
+    buf = _signed_buffer(1, dim, 3)
+    tau, m_ = jnp.asarray(0), jnp.asarray(m)
+    key = jax.random.PRNGKey(5)
+    got = read_dispatch(jnp.asarray(SCHEME_IDS[scheme]), buf, tau, m_, m_,
+                        key, dim)
+    want = _reference_read(scheme, buf, tau, m_, m_, key, dim)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    np.testing.assert_array_equal(_bits(got), _bits(buf[0]))
 
 
 @pytest.mark.parametrize("delay_kind", ["fixed", "uniform"])

@@ -152,6 +152,41 @@ def test_sharded_runner_compiles_without_collectives(topo, tpu_backend):
     assert "all-gather" not in hlo and "all-reduce" not in hlo
 
 
+def _coordinate_gathers(hlo, operand_shape):
+    """The compiled module's gathers that read single coordinates out of an
+    operand of ``operand_shape``: a gather whose slice is narrower than the
+    operand's last axis. HLO instruction names are unique in a module, so
+    an operand's shape is found at its definition."""
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]", hlo,
+                             re.MULTILINE))
+    found = []
+    for line in hlo.splitlines():
+        call = re.search(r"\bgather\(%([^,\s)]+)", line)
+        if call is None:
+            continue
+        shape = shapes.get(call.group(1), "")
+        sizes = re.search(r"slice_sizes=\{([\d,]*)\}", line).group(1)
+        if (shape == ",".join(map(str, operand_shape))
+                and int(sizes.split(",")[-1]) < operand_shape[-1]):
+            found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("algo", ["asysvrg", "hogwild"])
+def test_unlock_read_compiles_without_a_coordinate_gather(topo, tpu_backend,
+                                                          algo):
+    """The unlock reader selects over the ring buffer's slots: no gather
+    reads single coordinates out of the (rows, buf_len, p) buffer. The
+    locked readers' whole-row reads may stay gathers."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    hlo = _compile(algo, one_chip, one_chip).as_text()
+    resolved = _resolve(types.SimpleNamespace(n=N), _grid(algo)[0], EPOCHS)
+    buffer_shape = (len(_grid(algo)), resolved.buf_len, DIM)
+    assert f"f32[{','.join(map(str, buffer_shape))}]" in hlo
+    assert _coordinate_gathers(hlo, buffer_shape) == []
+    assert "read_unlock" in hlo          # its ops keep the reader's scope
+
+
 @pytest.mark.parametrize("how", ["spec", "env"])
 def test_fused_engine_on_tpu_raises_at_plan_time(tpu_backend, monkeypatch,
                                                   how):
