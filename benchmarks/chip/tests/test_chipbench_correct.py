@@ -9,6 +9,7 @@ gradient averaged over half of the data, an answer altered where the
 engine produces it.
 """
 import argparse
+import functools
 import time
 
 import jax
@@ -39,9 +40,11 @@ def test_control_in_bfloat16_fails_a_limit(config):
 @pytest.fixture
 def bench_run(monkeypatch, tmp_path):
     """Runs a cell through `driver.run` at a small size on the CPU, with a
-    fresh runner cache and the persistent compile cache in ``tmp_path``."""
+    fresh runner cache, and the persistent compile cache and the profile
+    in ``tmp_path``, so that runs in other workers cannot remove it."""
     from repro.service import cache
     monkeypatch.setattr(driver, "CACHE_DIR", tmp_path / "jax")
+    monkeypatch.setattr(driver, "TRACE_DIR", tmp_path / "trace")
     saved = {k: jax.config.values[k] for k in (
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
@@ -68,15 +71,95 @@ def _unchanged_epoch(monkeypatch):
                         lambda obj, data, w, *a, **k: w)
 
 
+def _objective_classes():
+    """`Objective` and every subclass of it that is loaded."""
+    import repro.core  # noqa: F401  (defines the subclasses)
+    from repro.core.objective import Objective
+    found, todo = [], [Objective]
+    while todo:
+        cls = todo.pop()
+        found.append(cls)
+        todo += cls.__subclasses__()
+    return found
+
+
+def _first_half(data, n):
+    """Every leaf of ``data`` that leads with the ``n`` samples, cut to the
+    first ``n // 2`` of them; the other leaves as they are."""
+    return jax.tree.map(
+        lambda a: a[:n // 2] if np.ndim(a) and a.shape[0] == n else a, data)
+
+
 def _half_the_data(monkeypatch):
-    from repro.core import objective
+    """Each objective's snapshot gradient taken by its own class's
+    `flat_full_grad`, over the first half of its samples."""
+    def on_half(real):
+        def half(self, data, w):
+            return real(self, _first_half(data, self.num_samples(data)), w)
+        return half
 
-    def half(self, data, w):
-        X, y, l2 = data
-        h = X.shape[0] // 2
-        return objective.full_grad_stable(X[:h], y[:h], l2, w)
+    for cls in _objective_classes():
+        if "flat_full_grad" in vars(cls):
+            monkeypatch.setattr(cls, "flat_full_grad",
+                                on_half(vars(cls)["flat_full_grad"]))
 
-    monkeypatch.setattr(objective.LogisticRegression, "flat_full_grad", half)
+
+def _configured(config):
+    """The objective that ``config`` runs, built by its own kind's module
+    at the small size, and the same over the first half of its data."""
+    cell = cells.load_cell(next(w["name"] for w in BENCH["workloads"]
+                                if w["config"] == config))
+    cfg = {**cell.config, **SMALL}
+    data = cell.objective.generate(cfg, 2**32 + 3)
+    n = jax.tree.leaves(data)[0].shape[0]
+    return (cell.objective.program(cfg, data),
+            cell.objective.program(cfg, _first_half(data, n)))
+
+
+TOY_N = 10
+_TOY = np.random.default_rng(7)
+_X = _TOY.standard_normal((TOY_N, 6)).astype(np.float32)
+_Y = np.where(_TOY.standard_normal(TOY_N) > 0, 1.0, -1.0).astype(np.float32)
+_TOKENS, _TARGETS = _TOY.integers(0, 8, (2, TOY_N, 4))
+
+
+def _toy(build):
+    """An objective over the toy samples, and the same over their first
+    half."""
+    return lambda: (build(TOY_N), build(TOY_N // 2))
+
+
+def _toy_cases():
+    from repro.core import LogisticRegression, MLPObjective, NonconvexLogistic
+    return {
+        "LogisticRegression": _toy(
+            lambda n: LogisticRegression(_X[:n], _Y[:n])),
+        "NonconvexLogistic": _toy(
+            lambda n: NonconvexLogistic(_X[:n], _Y[:n])),
+        "MLPObjective": _toy(lambda n: MLPObjective(
+            _TOKENS[:n], _TARGETS[:n], 8, d_model=4, d_hidden=8)),
+    }
+
+
+HALVED = {**{f"config-{c['name']}": functools.partial(_configured, c["name"])
+             for c in BENCH["configs"]}, **_toy_cases()}
+
+
+@pytest.mark.parametrize("case", HALVED)
+def test_half_the_data_takes_the_gradient_over_the_first_half(monkeypatch,
+                                                              case):
+    """The fault changes the snapshot gradient to the objective's own
+    gradient over the first half of its samples: for the objective of
+    every configuration, and for each objective class of `repro.core`."""
+    obj, first_half = HALVED[case]()
+    w = 0.5 * np.random.default_rng(11).standard_normal(
+        obj.flat_dim).astype(np.float32)
+    whole = np.asarray(obj.flat_full_grad(obj.data_args(), w))
+    want = np.asarray(first_half.flat_full_grad(first_half.data_args(), w))
+    _half_the_data(monkeypatch)
+    got = np.asarray(obj.flat_full_grad(obj.data_args(), w))
+    assert not np.allclose(got, whole)
+    np.testing.assert_array_equal(got, want)
 
 
 def _altered_answer(monkeypatch):
@@ -87,7 +170,7 @@ def _altered_answer(monkeypatch):
         hist, w = real(*args, **kw)
         w = w.copy()
         top = np.argmax(np.abs(w), axis=1)
-        w[np.arange(len(w)), top] *= 1.01
+        w[np.arange(len(w)), top] *= 1.05
         return hist, w
 
     monkeypatch.setattr(scheduler, "_dispatch_group", altered)
@@ -118,9 +201,9 @@ def test_traced_run_reports_what_the_cpu_can_read(bench_run, monkeypatch,
     on the CPU the trace holds no TPU plane, so the device metrics are
     left out rather than read as 0."""
     result = bench_run(cell, trace=1)
-    layer = {m["name"] for m in cells.load_cell(cell).per_layer}
-    device_metrics = {"device_idle", "svrg_update_us"}
-    assert set(result["metrics"]) == layer - device_metrics
+    per_layer = cells.load_cell(cell).per_layer
+    assert set(result["metrics"]) == {m["name"] for m in per_layer
+                                      if m["source"] != "device_trace"}
     assert all(v["value"] > 0 for v in result["metrics"].values())
     assert result["device"]["busy_s"] == 0.0
     assert result["device"]["window_s"] > 0

@@ -190,7 +190,8 @@ def test_program_runs_are_the_module_events_in_the_window(hand):
 
 
 def _reader(name, scope_trace, on_chip=False, chips=1):
-    r = NS(scopes=scope_trace, on_chip=on_chip, cell=NS(chips=chips))
+    r = NS(scopes=scope_trace, trace=None, on_chip=on_chip,
+           cell=NS(chips=chips))
     return cells.metric_reader(name)(r)
 
 
@@ -204,7 +205,9 @@ def test_scope_metrics_by_hand(hand):
 
 SCOPE_METRICS = {"inner_step_us": "inner_step", "read_us": "read",
                  "snapshot_ms": "snapshot", "loss_ms": "loss"}
-DEVICE_METRICS = [*SCOPE_METRICS, "idle_between_programs"]
+DEVICE_METRICS = sorted({*SCOPE_METRICS, "idle_between_programs", *(
+    m["name"] for m in cells.load_benchmark()["per_layer"]
+    if m["source"] == "device_trace")})
 
 
 @pytest.mark.parametrize("metric", DEVICE_METRICS)
