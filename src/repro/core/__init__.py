@@ -1,4 +1,5 @@
 from repro.core.objective import (
+    EllLogisticRegression,
     LogisticRegression,
     Objective,
     get_objective,
@@ -37,6 +38,7 @@ from repro.core.compression import (
 )
 
 __all__ = [
+    "EllLogisticRegression",
     "LogisticRegression",
     "Objective",
     "register_objective",
