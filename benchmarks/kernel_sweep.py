@@ -76,8 +76,11 @@ def run(quick: bool = False):
         vmap_specs = _specs(rows, inner, "vmap")
         fused_specs = _specs(rows, inner, "fused")
         plan = plan_sweep(obj, epochs, fused_specs)
-        (_, _, total, _, buf_len, fused_flag), = plan.groups
-        assert fused_flag, "fused specs must plan onto the fused group key"
+        # one group per scheme, all of one compiled shape
+        key_, *_ = plan.groups
+        total, buf_len = key_.total, key_.buf_len
+        assert all(k.fused for k in plan.groups), \
+            "fused specs must plan onto the fused group key"
 
         r_vmap = run_sweep(obj, epochs, vmap_specs)
         r_fused = run_sweep(obj, epochs, fused_specs)

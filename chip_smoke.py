@@ -9,8 +9,9 @@ device). A `SweepServer` (service + flush daemon + HTTP) serves three
 tenants whose `SweepClient` requests together make the paper's §5 grid:
 the three read schemes × two step sizes of AsySVRG at 10 threads and
 τ = 9, one Hogwild! row and one serial SVRG row, three epochs each. The
-grid is three compiled groups; the asysvrg group pools rows of two
-tenants. A second, warm wave of the same shapes follows.
+grid is three compiled programs and five groups, one per algo and read
+scheme; each AsySVRG group pools rows of two tenants. A second, warm wave
+of the same shapes follows.
 
 Four chips (``--chips 4``) runs only the row-sharded path: the same grid
 on `make_sweep_mesh(4)` through `run_sweep(mesh=...)` and through
@@ -114,20 +115,21 @@ def build_objective(seed: int, scale: float = 1.0) -> LogisticRegression:
 def tenant_grids(wave: int) -> dict:
     """The paper's §5 grid split over three tenants: AsySVRG's three read
     schemes × two step sizes at 10 threads and τ = 9 (tenants a and b share
-    that group), a Hogwild! row and a serial SVRG row. Waves differ only
-    in their seeds, so every wave has the same compiled shapes."""
+    each scheme's group), a Hogwild! row and a serial SVRG row. Waves
+    differ only in their seeds, so every wave has the same compiled
+    shapes."""
     def asy(scheme, step, seed):
         return SweepSpec(algo="asysvrg", scheme=scheme, step_size=step,
                          tau=9, num_threads=10, seed=seed)
 
     s = 100 * wave
+    schemes = ("consistent", "inconsistent", "unlock")
     return {
-        "tenant-a": [asy(sc, st, s + i) for i, (sc, st) in enumerate(
-            [(sc, st) for sc in ("consistent", "inconsistent")
-             for st in (1.0, 2.0)])],
-        "tenant-b": [asy("unlock", 1.0, s + 10), asy("unlock", 2.0, s + 11),
-                     SweepSpec(algo="hogwild", scheme="unlock", step_size=1.0,
-                               tau=9, num_threads=10, seed=s + 12)],
+        "tenant-a": [asy(sc, 1.0, s + i) for i, sc in enumerate(schemes)],
+        "tenant-b": [asy(sc, 2.0, s + 10 + i)
+                     for i, sc in enumerate(schemes)]
+        + [SweepSpec(algo="hogwild", scheme="unlock", step_size=1.0, tau=9,
+                     num_threads=10, seed=s + 13)],
         "tenant-c": [SweepSpec(algo="svrg", step_size=1.0, num_threads=1,
                                seed=s + 20)],
     }
@@ -170,13 +172,13 @@ def check_kernel_in_runner(obj, plan) -> None:
     `svrg_update` kernel (a ``tpu_custom_call`` in their HLO)."""
     w_init = obj.init_flat()
     for key_, members in plan.groups.items():
-        _, engine, total, option, buf_len, fused = key_
+        engine = key_.engine
         if engine != "asysvrg":
             continue
-        runner = get_group_runner(engine, group_epochs=EPOCHS, total=total,
-                                  option=option, buf_len=buf_len,
-                                  drop_prob=DROP_PROB, mesh=None, obj=obj,
-                                  fused=fused)
+        runner = get_group_runner(engine, group_epochs=EPOCHS,
+                                  total=key_.total, option=key_.option,
+                                  buf_len=key_.buf_len, drop_prob=DROP_PROB,
+                                  mesh=None, obj=obj, fused=key_.fused)
         args = _row_args(engine, plan.specs, plan.resolved, members, w_init)
         with uncounted_trace():
             hlo = runner.lower(*obj.data_args(), *args).as_text()
@@ -185,10 +187,12 @@ def check_kernel_in_runner(obj, plan) -> None:
 
 
 def check_against_reference(obj, plan, specs, hist, final_w) -> None:
-    """Rerun the asysvrg group with `svrg_update` on its jnp oracle and
-    compare with the compiled kernel's rows of ``hist`` / ``final_w``."""
-    (key_, members), = [(k, m) for k, m in plan.groups.items()
-                        if k[1] == "asysvrg" and len(m) > 1]
+    """Rerun the AsySVRG rows (the asysvrg engine's groups of more than one
+    row, one per scheme) with `svrg_update` on its jnp oracle and compare
+    with the compiled kernel's rows of ``hist`` / ``final_w``."""
+    keys = [k for k, m in plan.groups.items()
+            if k.engine == "asysvrg" and len(m) > 1]
+    members = [c for k in keys for c in plan.groups[k]]
     base = cache_stats()
     os.environ[KERNEL_MODE_ENV] = "reference"
     try:
@@ -205,7 +209,8 @@ def check_against_reference(obj, plan, specs, hist, final_w) -> None:
                       / np.abs(ref.histories)).max())
     w_rel = float((np.abs(final_w[members] - ref.final_w).max(axis=1)
                    / np.abs(ref.final_w).max(axis=1)).max())
-    emit("kernel_vs_reference", group=group_label(key_), rows=len(members),
+    emit("kernel_vs_reference", groups=[group_label(k) for k in keys],
+         rows=len(members),
          wall_s=wall, max_rel_history_diff=hist_rel,
          max_rel_final_w_diff=w_rel)
     check(hist_rel <= REF_HIST_RTOL and w_rel <= REF_W_RTOL,

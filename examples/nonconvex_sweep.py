@@ -68,7 +68,7 @@ def main():
 
     stats = svc.stats()
     print(f"\none flush: {stats.rows_submitted} rows, "
-          f"{stats.groups_dispatched} compiled groups "
+          f"{stats.groups_dispatched} dispatched groups "
           "(objectives never share a group)")
 
 

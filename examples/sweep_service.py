@@ -49,16 +49,17 @@ def main():
     rid_a = svc.submit(make_grid(schemes=("inconsistent",), seeds=(3, 4),
                                  step_sizes=(1.0, 2.0), taus=(9,),
                                  num_threads=10))
-    rid_b = svc.submit([SweepSpec(scheme="unlock", step_size=1.0, tau=9,
-                                  num_threads=10, seed=5),
-                        svrg_sweep_spec(step_size=1.0)])
+    specs_b = [SweepSpec(scheme="inconsistent", step_size=1.0, tau=9,
+                         num_threads=10, seed=5),
+               svrg_sweep_spec(step_size=1.0)]
+    rid_b = svc.submit(specs_b)
     rid_c = svc.submit([SweepSpec(algo="hogwild", scheme="unlock",
                                   step_size=1.0, tau=9, num_threads=10,
                                   epochs=9)])
     svc.flush()
     stats = svc.stats()
     print(f"3 requests, {stats.rows_submitted} rows -> "
-          f"{stats.groups_dispatched} compiled groups "
+          f"{stats.groups_dispatched} dispatched groups "
           f"({stats.rows_coalesced} rows coalesced across requests, "
           f"cache hit rate {stats.cache_hit_rate:.0%})")
     for rid, name in ((rid_a, "tenant A"), (rid_b, "tenant B"),
@@ -70,9 +71,7 @@ def main():
 
     # each tenant's demuxed result == its own standalone run_sweep
     res_b = svc.result(rid_b)
-    base_b = run_sweep(obj, 3, [SweepSpec(scheme="unlock", step_size=1.0,
-                                          tau=9, num_threads=10, seed=5),
-                                svrg_sweep_spec(step_size=1.0)])
+    base_b = run_sweep(obj, 3, specs_b)
     np.testing.assert_array_equal(res_b.histories, base_b.histories)
     print("  demuxed results bit-identical to standalone run_sweep\n")
 

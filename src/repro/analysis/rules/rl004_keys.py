@@ -12,8 +12,9 @@ The checker is structural, anchored on the shapes that actually exist in
 ``repro/core/sweep.py`` and ``repro/service/cache.py``:
 
   1. Every field of the ``_Resolved`` NamedTuple must either appear as an
-     ``r.<field>`` element of the group-key tuple built via
-     ``groups.setdefault((...), ...)`` in ``plan_sweep``, or be packed
+     ``r.<field>`` element of the group key built via
+     ``groups.setdefault((...), ...)`` or ``groups.setdefault(Key(...),
+     ...)`` in ``plan_sweep``, or be packed
      into the per-row runtime arrays in ``_row_args`` / ``_dispatch_group``
      (``resolved[c].<field>`` / ``specs[c].<field>``). A field that is
      neither keyed nor row-data can silently alias groups — exactly the
@@ -64,14 +65,21 @@ def _resolved_fields(cls: ast.ClassDef) -> List[ast.AnnAssign]:
 
 
 def _group_key_attrs(plan: ast.AST) -> Set[str]:
-    """Attribute names used in the tuple handed to groups.setdefault()."""
+    """Attribute names used in the key handed to groups.setdefault(): a
+    tuple literal, or a key type's constructor call (a NamedTuple)."""
     attrs: Set[str] = set()
     for node in ast.walk(plan):
         if (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "setdefault" and node.args
-                and isinstance(node.args[0], ast.Tuple)):
-            for el in node.args[0].elts:
+                and node.func.attr == "setdefault" and node.args):
+            key = node.args[0]
+            if isinstance(key, ast.Tuple):
+                elts = key.elts
+            elif isinstance(key, ast.Call):
+                elts = [*key.args, *(kw.value for kw in key.keywords)]
+            else:
+                continue
+            for el in elts:
                 if isinstance(el, ast.Attribute):
                     attrs.add(el.attr)
     return attrs
@@ -102,7 +110,7 @@ def _check_sweep(sf: SourceFile, out: List[Diagnostic]) -> None:
     if not keyed:
         out.append(Diagnostic(
             sf.path, plan.lineno, "RL004",
-            "plan_sweep builds no groups.setdefault((...)) key tuple — "
+            "plan_sweep builds no groups.setdefault((...)) key — "
             "the group-key anchor RL004 checks against is gone; restore "
             "it or update the checker"))
         return

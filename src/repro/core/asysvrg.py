@@ -29,8 +29,12 @@ case; `repro.core.hogwild` reuses the dispatch-as-data pieces for the
 baseline). Two design rules make the batched run BIT-IDENTICAL to the
 sequential driver here:
 
-  1. scheme / delay-kind dispatch is data (``lax.switch`` / ``where``), not
-     Python control flow, so a config batch shares one trace;
+  1. scheme / delay-kind dispatch is data (``lax.switch`` / ``where``),
+     not Python control flow, so a config batch shares one trace. The
+     scheme is taken once per epoch (`by_scheme`): one branch per scheme
+     holds the whole inner loop with that scheme's reader and, for unlock
+     alone, the drop mask (the sweep passes a group's one scheme
+     unbatched, so the switch stays a branch);
   2. every reduction is either elementwise, a row-reduce over a trailing
      axis, or a fixed-order `lax.scan` accumulation (see
      objective.loss_fixed_order) — the shapes XLA:CPU reduces identically
@@ -147,40 +151,66 @@ _READERS = {
     "inconsistent": _read_inconsistent,
     "unlock": _read_unlock,
 }
-# switch branches in SCHEME_IDS order
+# readers by scheme id, in SCHEME_IDS order
 _READER_LIST = (_read_consistent, _read_inconsistent, _read_unlock)
 
 
-def read_dispatch(scheme_id, buffer, tau, a, m, key, dim: int):
-    """`lax.switch` over the three reading schemes.
+def by_scheme(scheme_id, run):
+    """``run(scheme)`` for the scheme ``scheme_id`` names: a `lax.switch`
+    over one trace of ``run`` per scheme, each with its scheme a Python
+    int, so a branch holds only its own scheme's work.
 
-    ``scheme_id``/``tau`` may be traced (one trace serves every scheme in a
-    sweep batch); ``dim`` is static. The ring-buffer slot arithmetic uses the
-    DYNAMIC τ, so a buffer padded to any length ≥ τ+1 reads identically.
-    A buffer of exactly one slot (τ = 0 and one thread: `run_asysvrg` at
-    τ = 0, or a sweep row with ``num_threads=1``; `_row_buf_len` pads other
-    τ = 0 rows to their thread count) holds the only iterate any scheme can
-    read, so it is returned with no dispatch. That keeps such a sweep row
-    bit-identical to `run_asysvrg` on XLA:CPU, whose fused code otherwise
-    differs between the two programs by an ulp.
+    Where ``scheme_id`` is one scalar for the program (`run_asysvrg`,
+    `run_hogwild`, and a sweep group, whose key holds its scheme) the
+    device runs one branch; a vmap that batches ``scheme_id`` turns the
+    switch into a select that runs every branch, with the same bits.
     """
-    if buffer.shape[0] == 1:
-        with jax.named_scope(READ_SCOPE):
-            return buffer[0]
-    buf_len = tau + 1
+    return jax.lax.switch(
+        scheme_id, [lambda s=s: run(s) for s in range(len(_READER_LIST))])
 
-    def slot_of(age):
-        return jnp.mod(age, buf_len)
 
-    def branch(reader, scope):
-        def read(ops):
-            with jax.named_scope(scope):
-                return reader(ops[0], slot_of, ops[1], ops[2], ops[3], dim)
-        return read
+def read_dispatch(scheme: int, buffer, tau, a, m, key, dim: int):
+    """Read u under reading scheme ``scheme``, an id fixed at trace time
+    (a `by_scheme` branch's).
 
-    branches = [branch(r, s) for r, s in zip(_READER_LIST, READER_SCOPES)]
+    ``tau`` may be traced; ``dim`` is static. The ring-buffer slot
+    arithmetic uses the DYNAMIC τ, so a buffer padded to any length ≥ τ+1
+    reads identically. A buffer of exactly one slot (τ = 0 and one
+    thread: `run_asysvrg` at τ = 0, or a sweep row with
+    ``num_threads=1``; `_row_buf_len` pads other τ = 0 rows to their
+    thread count) holds the only iterate any scheme can read, so it is
+    returned with no reader. That keeps such a sweep row bit-identical to
+    `run_asysvrg` on XLA:CPU, whose fused code otherwise differs between
+    the two programs by an ulp.
+    """
     with jax.named_scope(READ_SCOPE):
-        return jax.lax.switch(scheme_id, branches, (buffer, a, m, key))
+        if buffer.shape[0] == 1:
+            return buffer[0]
+        buf_len = tau + 1
+
+        def slot_of(age):
+            return jnp.mod(age, buf_len)
+
+        with jax.named_scope(READER_SCOPES[scheme]):
+            return _READER_LIST[scheme](buffer, slot_of, a, m, key, dim)
+
+
+def drop_writes(scheme: int, key, drop_prob: float, grads):
+    """The unlock write-write race: for ``scheme`` unlock (an id fixed at
+    trace time), ``grads`` times one Bernoulli(1 − ``drop_prob``) 0/1 mask
+    of their width; for the other schemes ``grads`` unmasked.
+
+    The mask is drawn once and kept: left to itself, XLA on a TPU fuses
+    the p-wide draw into each masked product and draws it once per
+    product.
+    """
+    grads = tuple(grads)
+    if scheme != _UNLOCK or drop_prob <= 0:
+        return grads
+    with jax.named_scope(DROP_MASK_SCOPE):
+        keep = jax.lax.optimization_barrier(jax.random.bernoulli(
+            key, 1.0 - drop_prob, grads[0].shape).astype(grads[0].dtype))
+        return tuple(x * keep for x in grads)
 
 
 def _epoch_core(obj: Objective, data, w, key, eta, tau, scheme_id, delay_id,
@@ -210,39 +240,41 @@ def _epoch_core(obj: Objective, data, w, key, eta, tau, scheme_id, delay_id,
     delays = _delay_schedule_core(delay_id, total, tau, k_delay)
 
     buffer = jnp.tile(u0[None, :], (buf_len, 1))        # slot m%(τ+1) = u_m
+    keys = jax.random.split(k_scan, total)
+    ms = jnp.arange(total)
 
-    def body(carry, inp):
-        with jax.named_scope(INNER_STEP_SCOPE):
-            u, buffer, acc = carry
-            m, i, d, k = inp
-            k_read, k_drop = jax.random.split(k)
-            a = jnp.maximum(m - d, 0)
-            u_read = read_dispatch(scheme_id, buffer, tau, a, m, k_read, dim)
-            with jax.named_scope(SAMPLE_GRAD_SCOPE):
-                g = obj.flat_sample_grad(data, i, u_read)
-                g0 = obj.flat_sample_grad(data, i, u0)
-            gf = mu
-            if drop_prob > 0:
+    def inner_loop(scheme: int):
+        def body(carry, inp):
+            with jax.named_scope(INNER_STEP_SCOPE):
+                u, buffer, acc = carry
+                m, i, d, k = inp
+                k_read, k_drop = jax.random.split(k)
+                a = jnp.maximum(m - d, 0)
+                u_read = read_dispatch(scheme, buffer, tau, a, m, k_read, dim)
+                # A fusion boundary (m >= 0 always holds): it keeps the read
+                # out of the gradient's fusion, as a per-step switch on the
+                # scheme did, so XLA:CPU gives a row the same gradient bits
+                # at every group width (an optimization_barrier does not).
+                u_read = jax.lax.cond(m >= 0, lambda x: x, lambda x: x * 0,
+                                      u_read)
+                with jax.named_scope(SAMPLE_GRAD_SCOPE):
+                    g = obj.flat_sample_grad(data, i, u_read)
+                    g0 = obj.flat_sample_grad(data, i, u0)
                 # unlock write-write race: drop a random coordinate
                 # fraction. Masking the three inputs with the same 0/1 mask
                 # equals masking v = g − g0 + gf (exact for 0/1 factors),
                 # which keeps the update expressible as the fused kernel's
                 # 4-read form.
-                with jax.named_scope(DROP_MASK_SCOPE):
-                    keep = jax.random.bernoulli(
-                        k_drop, 1.0 - drop_prob, (dim,)).astype(u.dtype)
-                    mask = jnp.where(scheme_id == _UNLOCK, keep,
-                                     jnp.ones_like(keep))
-                    g, g0, gf = g * mask, g0 * mask, gf * mask
-            u_next = svrg_update_ops.apply_leaf(u, g, g0, gf, eta)
-            buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
-            return (u_next, buffer, acc + u_next), None
+                g, g0, gf = drop_writes(scheme, k_drop, drop_prob, (g, g0, mu))
+                u_next = svrg_update_ops.apply_leaf(u, g, g0, gf, eta)
+                buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
+                return (u_next, buffer, acc + u_next), None
 
-    keys = jax.random.split(k_scan, total)
-    ms = jnp.arange(total)
-    (u_last, _, acc), _ = jax.lax.scan(
-        body, (u0, buffer, jnp.zeros_like(u0)), (ms, idx, delays, keys))
+        (u_last, _, acc), _ = jax.lax.scan(
+            body, (u0, buffer, jnp.zeros_like(u0)), (ms, idx, delays, keys))
+        return u_last, acc
 
+    u_last, acc = by_scheme(scheme_id, inner_loop)
     return u_last if option == 1 else acc / total
 
 

@@ -10,7 +10,7 @@ Hogwild!").
 
 Like `repro.core.asysvrg`, the epoch body (`_hogwild_epoch_core`) is written
 to be `vmap`-able over a batch of (seed, scheme, step, τ, delay-kind, decay)
-configurations: scheme/delay dispatch is data (`read_dispatch` /
+configurations: scheme/delay dispatch is data (`by_scheme` /
 `_delay_schedule_core`), every reduction is vmap-bitwise-stable, and the
 per-epoch γ ← decay·γ schedule is threaded through the `lax.scan` carry of
 `_hogwild_epochs_core` so the whole multi-epoch run — decay included — is
@@ -25,15 +25,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.asysvrg import (
-    _UNLOCK,
     AsyRunResult,
     DELAY_IDS,
-    DROP_MASK_SCOPE,
     INNER_STEP_SCOPE,
     LOSS_SCOPE,
     SAMPLE_GRAD_SCOPE,
     SCHEME_IDS,
     _delay_schedule_core,
+    by_scheme,
+    drop_writes,
     read_dispatch,
 )
 from repro.core.objective import Objective
@@ -68,31 +68,30 @@ def _hogwild_epoch_core(obj: Objective, data, w, key, gamma, tau, scheme_id,
     idx = jax.random.randint(k_idx, (total,), 0, n)
     delays = _delay_schedule_core(delay_id, total, tau, k_delay)
     buffer = jnp.tile(w[None, :], (buf_len, 1))         # slot m%(τ+1) = u_m
-
-    def body(carry, inp):
-        with jax.named_scope(INNER_STEP_SCOPE):
-            u, buffer = carry
-            m, i, d, k = inp
-            k_read, k_drop = jax.random.split(k)
-            a = jnp.maximum(m - d, 0)
-            u_read = read_dispatch(scheme_id, buffer, tau, a, m, k_read, dim)
-            with jax.named_scope(SAMPLE_GRAD_SCOPE):
-                v = obj.flat_sample_grad(data, i, u_read)
-            if drop_prob > 0:
-                # unlock write-write race: drop a random coordinate fraction
-                with jax.named_scope(DROP_MASK_SCOPE):
-                    keep = jax.random.bernoulli(
-                        k_drop, 1.0 - drop_prob, (dim,)).astype(u.dtype)
-                    mask = jnp.where(scheme_id == _UNLOCK, keep,
-                                     jnp.ones_like(keep))
-                    v = v * mask
-            u_next = u - gamma * v
-            buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
-            return (u_next, buffer), None
-
     keys = jax.random.split(k_scan, total)
     ms = jnp.arange(total)
-    (u_last, _), _ = jax.lax.scan(body, (w, buffer), (ms, idx, delays, keys))
+
+    def inner_loop(scheme: int):
+        def body(carry, inp):
+            with jax.named_scope(INNER_STEP_SCOPE):
+                u, buffer = carry
+                m, i, d, k = inp
+                k_read, k_drop = jax.random.split(k)
+                a = jnp.maximum(m - d, 0)
+                u_read = read_dispatch(scheme, buffer, tau, a, m, k_read, dim)
+                with jax.named_scope(SAMPLE_GRAD_SCOPE):
+                    v = obj.flat_sample_grad(data, i, u_read)
+                # unlock write-write race: drop a random coordinate fraction
+                v, = drop_writes(scheme, k_drop, drop_prob, (v,))
+                u_next = u - gamma * v
+                buffer = buffer.at[jnp.mod(m + 1, tau + 1)].set(u_next)
+                return (u_next, buffer), None
+
+        (u_last, _), _ = jax.lax.scan(body, (w, buffer),
+                                      (ms, idx, delays, keys))
+        return u_last
+
+    u_last = by_scheme(scheme_id, inner_loop)
     return u_last
 
 

@@ -60,15 +60,20 @@ collectives). It is CALIBRATED AGAINST XLA:CPU reduction behaviour and must
 be re-validated per backend before the sharded path is trusted on TPU/GPU.
 
 Grouping: specs are grouped by the STATIC dims of their compiled program —
-(engine, M̃, option, buf_len) — compiled once per group, and rows reassemble
-in input order. ``buf_len`` (the delay ring-buffer length) is pinned PER
+(engine, M̃, option, buf_len) — and by their reading scheme, and rows
+reassemble in input order. The scheme is not static: groups that differ
+only in it run one compiled program, which takes the group's scheme as one
+unbatched scalar, so the epoch cores' `lax.switch` on it (`by_scheme`,
+one branch per scheme around the whole inner loop) is a real branch and
+each group's device work is only its own scheme's reader and, for unlock
+alone, the drop mask. ``buf_len`` (the delay ring-buffer length) is pinned PER
 ROW at resolve time from the row's own (τ, num_threads): adding an
 unrelated high-τ row to a sweep can therefore never change another row's
 compiled program shape (it lands in its own group). Rows that should share
 a group share a thread count, which the paper's grids do; the ring-buffer
 slot arithmetic uses the dynamic τ, so buf_len only affects shapes, never
-bits. A grid over schemes / seeds / steps / τ / delay-kinds / epochs at one
-thread count is one group per algo.
+bits. A grid over seeds / steps / τ / delay-kinds / epochs at one thread
+count is one group per algo and scheme, and one compiled program per algo.
 
 **Persistent compiled runners.** The group bodies (`_asysvrg_group_fn` /
 `_hogwild_group_fn`) close over the objective's PURE methods + static
@@ -111,6 +116,7 @@ ALGOS = ("asysvrg", "hogwild", "svrg")
 _ENGINE_ASYSVRG = "asysvrg"
 _ENGINE_HOGWILD = "hogwild"
 _DATA_AXIS = "data"
+_SCHEME_NAMES = {i: name for name, i in SCHEME_IDS.items()}
 
 # engine modes: how a group's epoch scan executes. "vmap" batches the
 # per-row epochs cores with jax.vmap (per-update XLA op dispatch); "fused"
@@ -387,13 +393,28 @@ def _executed_spec(spec: SweepSpec, r: _Resolved) -> SweepSpec:
                                engine_mode="fused" if r.fused else "vmap")
 
 
-# (objective fingerprint, engine, M̃, option, buf_len, fused) — the
-# fingerprint covers the objective's static config AND data bytes, so the
-# service scheduler can pool rows from different requests without ever
-# coalescing two objectives (or two datasets) into one compiled dispatch.
-# ``fused`` (the resolved engine mode) sits LAST so key_[0] stays the
-# objective fingerprint everywhere the scheduler peeks at it.
-_GroupKey = Tuple[int, str, int, int, int, bool]
+class _GroupKey(NamedTuple):
+    """What a group of rows shares: the static dims of its compiled
+    program and its reading scheme. The fingerprint covers the objective's
+    static config AND data bytes, so the service scheduler can pool rows
+    from different requests without ever coalescing two objectives (or two
+    datasets) into one compiled dispatch. ``scheme_id`` keeps a dispatch to
+    one reading scheme, which the group body takes unbatched (see the
+    module text); it is no dim of the compiled program, so groups whose
+    keys differ only in it share one runner (and, at one width, one
+    trace: ``key._replace(scheme_id=None)``)."""
+    fingerprint: int
+    engine: str
+    total: int                 # M̃, the inner steps of an epoch
+    option: int
+    buf_len: int
+    scheme_id: int
+    fused: bool                # the resolved engine mode
+
+    @property
+    def scheme(self) -> str:
+        """The reading scheme every row of the group runs."""
+        return _SCHEME_NAMES[self.scheme_id]
 
 
 class SweepPlan(NamedTuple):
@@ -435,10 +456,11 @@ def plan_sweep(obj: Optional[Objective], epochs: int,
     """Normalize + resolve specs and group them by compiled-program shape.
 
     Exposed for tests and capacity planning: the group keys are the static
-    dims (objective fingerprint, engine, M̃, option, buf_len, fused), all
-    pinned per-row, so a row's key never depends on which other rows share
-    the sweep. ``obj`` may be None when every spec names a registered
-    objective.
+    dims (objective fingerprint, engine, M̃, option, buf_len, fused) and
+    the row's resolved scheme id, all pinned per-row, so a row's key never
+    depends on which other rows share the sweep. Rows of different schemes
+    land in different groups of one compiled program. ``obj`` may be None
+    when every spec names a registered objective.
     """
     specs = tuple(_normalize_spec(s) for s in specs)
     if not specs:
@@ -450,8 +472,8 @@ def plan_sweep(obj: Optional[Objective], epochs: int,
     groups: Dict[_GroupKey, List[int]] = {}
     for c, r in enumerate(resolved):
         groups.setdefault(
-            (ofp, r.engine, r.total, r.option, r.buf_len, r.fused),
-            []).append(c)
+            _GroupKey(ofp, r.engine, r.total, r.option, r.buf_len,
+                      r.scheme_id, r.fused), []).append(c)
     return SweepPlan(specs=specs, resolved=resolved, groups=groups,
                      objective=obj)
 
@@ -495,6 +517,10 @@ def _asysvrg_group_fn(obj: Objective, num_data: int, epochs: int, total: int,
     function can live in the persistent runner cache (repro.service.cache)
     and any same-``runner_static_key`` objective's data reuses one compiled
     program.
+
+    The group key holds one scheme, so every row's scheme id is equal:
+    the body takes ``scheme_ids[0]`` unbatched (``in_axes=None``), which
+    keeps the epoch cores' switch on the scheme a real branch.
     """
 
     def group(*all_args):
@@ -508,8 +534,8 @@ def _asysvrg_group_fn(obj: Objective, num_data: int, epochs: int, total: int,
                 epochs=epochs, total=total, buf_len=buf_len, option=option,
                 drop_prob=drop_prob, row_epochs=row_epochs)
 
-        return jax.vmap(per_config)(keys, etas, taus, scheme_ids, delay_ids,
-                                    row_epochs, w0_rows)
+        return jax.vmap(per_config, in_axes=(0, 0, 0, None, 0, 0, 0))(
+            keys, etas, taus, scheme_ids[0], delay_ids, row_epochs, w0_rows)
 
     return group
 
@@ -517,8 +543,8 @@ def _asysvrg_group_fn(obj: Objective, num_data: int, epochs: int, total: int,
 def _hogwild_group_fn(obj: Objective, num_data: int, epochs: int, total: int,
                       buf_len: int, drop_prob: float):
     """vmap(multi-epoch Hogwild! scan, γ-decay in the carry); pure methods +
-    statics only — data and row arrays enter at call time (see
-    `_asysvrg_group_fn`)."""
+    statics only — data and row arrays enter at call time, and the group's
+    one scheme unbatched (see `_asysvrg_group_fn`)."""
 
     def group(*all_args):
         data = all_args[:num_data]
@@ -532,8 +558,9 @@ def _hogwild_group_fn(obj: Objective, num_data: int, epochs: int, total: int,
                 epochs=epochs, total=total, buf_len=buf_len,
                 drop_prob=drop_prob, row_epochs=row_epochs)
 
-        return jax.vmap(per_config)(keys, gammas, decays, taus, scheme_ids,
-                                    delay_ids, row_epochs, w0_rows)
+        return jax.vmap(per_config, in_axes=(0, 0, 0, 0, None, 0, 0, 0))(
+            keys, gammas, decays, taus, scheme_ids[0], delay_ids, row_epochs,
+            w0_rows)
 
     return group
 
@@ -643,9 +670,10 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
                     resolved: Sequence[_Resolved], members: Sequence[int],
                     key_: _GroupKey, group_epochs: int, w_init,
                     drop_prob: float, mesh: Optional[Mesh]):
-    """Run ONE (objective, engine, M̃, option, buf_len) group through the
-    persistent runner cache; returns (histories [rows, group_epochs+1],
-    final_w [rows, flat_dim]) as numpy, padding rows already sliced off.
+    """Run ONE (objective, engine, M̃, option, buf_len, scheme) group
+    through the persistent runner cache; returns (histories [rows,
+    group_epochs+1], final_w [rows, flat_dim]) as numpy, padding rows
+    already sliced off.
 
     ``specs``/``resolved`` are row-aligned sequences indexed by ``members``
     — `run_sweep` passes a single plan's rows, the service scheduler a
@@ -655,10 +683,10 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
     """
     from repro.service.cache import get_group_runner
 
-    _, engine, total, option, buf_len, fused = key_
+    engine, total, fused = key_.engine, key_.total, key_.fused
     args = _row_args(engine, specs, resolved, members, w_init)
     runner = get_group_runner(engine, group_epochs=group_epochs, total=total,
-                              option=option, buf_len=buf_len,
+                              option=key_.option, buf_len=key_.buf_len,
                               drop_prob=drop_prob, mesh=mesh, obj=obj,
                               fused=fused)
     if mesh is not None:
@@ -676,7 +704,8 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
     tags = {}
     if tr.enabled:
         from repro.kernels.dispatch import mode_tags
-        tags = dict(engine=engine, rows=len(members), total=int(total),
+        tags = dict(engine=engine, scheme=key_.scheme,
+                    rows=len(members), total=int(total),
                     group_epochs=int(group_epochs), **mode_tags(fused))
     with tr.span_active("execute", **tags):
         w_fin, hist = runner(*obj.data_args(), *args)
@@ -688,10 +717,10 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
 
 
 def group_label(key_: _GroupKey) -> str:
-    """Human-readable label for one compiled group (progress ids)."""
-    _, engine, total, option, buf_len, fused = key_
-    return (f"{engine}-{'fused' if fused else 'vmap'}-M{int(total)}"
-            f"-opt{option}-buf{int(buf_len)}")
+    """Human-readable label for one dispatched group (progress ids)."""
+    return (f"{key_.engine}-{'fused' if key_.fused else 'vmap'}"
+            f"-M{int(key_.total)}-opt{key_.option}-buf{int(key_.buf_len)}"
+            f"-{key_.scheme}")
 
 
 def _assemble_result(specs: Tuple[SweepSpec, ...],
@@ -742,11 +771,12 @@ def run_sweep(obj: Optional[Objective], epochs: int,
               drop_prob: float = 0.02,
               mesh: Optional[Mesh] = None) -> SweepResult:
     """Run every spec for its epoch budget in one compiled program per
-    (objective, engine, M̃, option, buf_len) group, row-sharded across the
-    mesh `data` axis when one is active (explicit ``mesh=`` or the ambient
-    `repro.sharding.context` mesh). Histories/final iterates are
-    bit-identical to per-spec `run_asysvrg` / `run_hogwild` calls — sharded
-    or not (XLA:CPU-calibrated; re-validate per backend).
+    (objective, engine, M̃, option, buf_len), one dispatch per scheme,
+    row-sharded across the mesh `data` axis when one is active (explicit
+    ``mesh=`` or the ambient `repro.sharding.context` mesh).
+    Histories/final iterates are bit-identical to per-spec `run_asysvrg` /
+    `run_hogwild` calls — sharded or not (XLA:CPU-calibrated; re-validate
+    per backend).
 
     ``obj`` is any `repro.core.objective.Objective` (or None when every
     spec names a registered one); pytree objectives run on their FLAT

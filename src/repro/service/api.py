@@ -31,7 +31,7 @@ import dataclasses
 import threading
 import time
 import zlib
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -100,6 +100,10 @@ class ServiceStats:
     compiles: int
     rows_padded: int = 0         # stable-width pad rows ever dispatched
     rows_diverged: int = 0       # rows the divergence watchdog flagged
+    # groups flushes dispatched, per reading scheme; the non-unlock share
+    # is how often a group skipped the unlock read and write-drop mask
+    groups_by_scheme: Dict[str, int] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def cache_hit_rate(self) -> float:
@@ -179,6 +183,7 @@ class SweepService:
         self._rows_coalesced = 0  # guarded-by: _lock
         self._groups_dispatched = 0  # guarded-by: _lock
         self._groups_merged = 0  # guarded-by: _lock
+        self._groups_by_scheme: Counter = Counter()  # guarded-by: _lock
         self._rows_padded = 0  # guarded-by: _lock
         self._rows_diverged = 0  # guarded-by: _lock
         self._flushes = 0  # guarded-by: _lock
@@ -338,6 +343,7 @@ class SweepService:
             self._requests_completed += len(results)
             self._rows_coalesced += info.rows_coalesced
             self._groups_dispatched += info.groups_dispatched
+            self._groups_by_scheme.update(info.groups_by_scheme)
             self._groups_merged += info.groups_merged
             self._rows_padded += info.rows_padded
             self._rows_diverged += info.rows_diverged
@@ -535,7 +541,8 @@ class SweepService:
                 cache_misses=cache.misses,
                 compiles=cache.compiles,
                 rows_padded=self._rows_padded,
-                rows_diverged=self._rows_diverged)
+                rows_diverged=self._rows_diverged,
+                groups_by_scheme=dict(self._groups_by_scheme))
 
     # ------------------------------------------------------ checkpointed job
     def run_job(self, specs: Sequence[SweepSpec],

@@ -34,8 +34,9 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
-from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
-                    Tuple)
+from collections import Counter
+from typing import (Callable, Dict, List, Mapping, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 from jax.sharding import Mesh
@@ -86,14 +87,16 @@ FlushSelector = Callable[[Tuple[SweepRequest, ...]],
                          Tuple[Sequence[SweepRequest],
                                Sequence[SweepRequest]]]
 
-# A width policy maps (group key, merged epoch bound, natural row count) to
-# the row count actually dispatched (>= natural). Returning a previously
-# compiled width lets a warm service stay at 0 compiles even when the
-# pooled batch width drifts — the vmap row count is part of the traced
-# shape, so a NEW width retraces even on a runner-cache hit. Padding rows
-# repeat an existing member; per-row bits are batch-composition-independent
-# (the same contract the sharding padding relies on), so results are
-# unchanged and the pad rows are sliced off before demux.
+# A width policy maps (the group key less its scheme — groups that differ
+# only in it run one compiled program —, merged epoch bound, natural row
+# count) to the row count actually dispatched (>= natural). Returning a
+# previously compiled width lets a warm service stay at 0 compiles even
+# when the pooled batch width drifts — the vmap row count is part of the
+# traced shape, so a NEW width retraces even on a runner-cache hit. Padding
+# rows repeat an existing member; per-row bits are
+# batch-composition-independent (the same contract the sharding padding
+# relies on), so results are unchanged and the pad rows are sliced off
+# before demux.
 WidthPolicy = Callable[[tuple, int, int], int]
 
 
@@ -133,6 +136,9 @@ class DispatchInfo(NamedTuple):
     rows_padded: int = 0     # stable-width pad rows (wasted compute bought
     #                          against a retrace — see WidthPolicy)
     rows_diverged: int = 0   # rows the divergence watchdog flagged
+    # dispatched groups per reading scheme: a group runs only its scheme's
+    # reader and, unless unlock, draws no write-drop mask
+    groups_by_scheme: Mapping[str, int] = {}
 
 
 def coalesce(obj: Optional[Objective],
@@ -228,7 +234,8 @@ def dispatch(obj: Optional[Objective], batch: CoalescedBatch, *, w0=None,
         run_members = members
         if width_policy is not None:
             with tr.span_all(member_tids, "pad", parent_name="coalesce"):
-                width = int(width_policy(key_, group_epochs, len(members)))
+                width = int(width_policy(key_._replace(scheme_id=None),
+                                         group_epochs, len(members)))
                 if width < len(members):
                     raise ValueError(
                         f"width policy shrank group {key_}: {width} < "
@@ -237,13 +244,14 @@ def dispatch(obj: Optional[Objective], batch: CoalescedBatch, *, w0=None,
                                + [members[0]] * (width - len(members)))
                 rows_padded += width - len(members)
                 tr.annotate(natural=len(members), padded=len(run_members))
-        group_obj = batch.objectives[key_[0]]
+        group_obj = batch.objectives[key_.fingerprint]
         with tr.span_all(member_tids, "dispatch", parent_name="coalesce",
                          group_rows=len(run_members),
                          group_epochs=int(group_epochs)):
             hist, w_fin = _dispatch_group(group_obj, specs, resolved,
                                           run_members, key_, group_epochs,
-                                          w_inits[key_[0]], drop_prob, mesh)
+                                          w_inits[key_.fingerprint],
+                                          drop_prob, mesh)
         if watchdog is not None:
             from repro.obs.watchdog import enforce_group
 
@@ -254,7 +262,7 @@ def dispatch(obj: Optional[Objective], batch: CoalescedBatch, *, w0=None,
                     bisect.bisect_right(offsets, c) - 1].request.tenant,
                 redispatch=lambda amended: _dispatch_group(
                     group_obj, specs, amended, run_members, key_,
-                    group_epochs, w_inits[key_[0]], drop_prob, mesh),
+                    group_epochs, w_inits[key_.fingerprint], drop_prob, mesh),
                 allow_cancel_job=False)
             diverged_flat.update(bad)
             epoch_overrides.update(overrides)
@@ -303,5 +311,7 @@ def dispatch(obj: Optional[Objective], batch: CoalescedBatch, *, w0=None,
                         rows_coalesced=rows_coalesced,
                         groups_merged=groups_merged,
                         rows_padded=rows_padded,
-                        rows_diverged=len(diverged_flat))
+                        rows_diverged=len(diverged_flat),
+                        groups_by_scheme=Counter(
+                            k.scheme for k in batch.groups))
     return results, info

@@ -14,6 +14,8 @@ import subprocess
 import sys
 from pathlib import Path
 
+import pytest
+
 from repro.analysis import RULES, lint_paths, lint_source
 
 REPO = Path(__file__).resolve().parent.parent
@@ -263,6 +265,21 @@ def test_rl004_flags_unkeyed_resolved_field():
 
 def test_rl004_keyed_field_clean():
     assert lint_source(GOOD_RL004_SWEEP) == []
+
+
+@pytest.mark.parametrize("key, bad", [
+    ("_Key(r.engine)", True),
+    ("_Key(r.engine, buf_len=r.buf_len)", False),
+    ("_Key(r.engine, r.buf_len)", False),
+])
+def test_rl004_reads_a_named_key_constructor(key, bad):
+    """A group key built by a key type's constructor (a NamedTuple) is
+    read like a tuple literal: its positional and keyword fields."""
+    src = GOOD_RL004_SWEEP.replace("(r.engine, r.buf_len)", key)
+    diags = lint_source(src)
+    assert codes(diags) == (["RL004"] if bad else [])
+    if bad:
+        assert "_Resolved.buf_len" in diags[0].message
 
 
 def test_rl004_flags_key_param_never_read():

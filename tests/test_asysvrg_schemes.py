@@ -150,28 +150,29 @@ def test_unlock_read_equals_gather_bitwise(tau, buf_len, signed_zeros):
 
 
 def test_unlock_read_equals_gather_bitwise_vmap_mixed_schemes():
-    """Under `vmap` over rows of mixed schemes and τ (as the sweep runs
-    them), `read_dispatch` gives each unlock row the gather's bits and the
-    locked rows their own readers' bits."""
+    """Rows of mixed schemes and τ, as the sweep runs them (each scheme's
+    rows vmapped as one group): `read_dispatch` gives each unlock row the
+    gather's bits and the locked rows their own readers' bits."""
     dim, buf_len = 1000, 10
     schemes = ["consistent", "inconsistent", "unlock", "unlock",
                "inconsistent", "unlock"]
     taus = jnp.asarray([9, 4, 9, 0, 9, 4])
     ms = jnp.asarray([31, 12, 17, 5, 3, 40])
-    ids = jnp.asarray([SCHEME_IDS[s] for s in schemes])
     a = jnp.maximum(ms - taus, 0)
     keys = jax.random.split(jax.random.PRNGKey(11), len(schemes))
     bufs = jnp.stack([_signed_buffer(buf_len, dim, r)
                       for r in range(len(schemes))])
 
-    got = jax.jit(jax.vmap(
-        lambda s, b, t, a_, m, k: read_dispatch(s, b, t, a_, m, k, dim)))(
-            ids, bufs, taus, a, ms, keys)
-    for r, scheme in enumerate(schemes):
-        want = _reference_read(scheme, bufs[r], taus[r], a[r], ms[r], keys[r],
-                               dim)
-        np.testing.assert_array_equal(_bits(got[r]), _bits(want),
-                                      err_msg=f"row {r} ({scheme})")
+    for scheme, sid in SCHEME_IDS.items():
+        rows = jnp.asarray([r for r, s in enumerate(schemes) if s == scheme])
+        got = jax.jit(jax.vmap(
+            lambda b, t, a_, m, k: read_dispatch(sid, b, t, a_, m, k, dim)))(
+                bufs[rows], taus[rows], a[rows], ms[rows], keys[rows])
+        for j, r in enumerate(rows.tolist()):
+            want = _reference_read(scheme, bufs[r], taus[r], a[r], ms[r],
+                                   keys[r], dim)
+            np.testing.assert_array_equal(_bits(got[j]), _bits(want),
+                                          err_msg=f"row {r} ({scheme})")
 
 
 @pytest.mark.parametrize("scheme", list(SCHEME_IDS))

@@ -68,10 +68,14 @@ def test_fused_matches_vmap_all_algos(obj):
 @pytest.mark.parametrize("rows", [1, 3, 8])
 def test_fused_group_widths(obj, rows):
     """One-row groups, odd widths and vector-width multiples all hit the
-    same grid mapping: fused == vmap bit-exact at every group width."""
-    specs = [SweepSpec(scheme=SCHEMES[c % 3], step_size=0.2, tau=3,
-                       num_threads=4, inner_steps=15, seed=c)
-             for c in range(rows)]
+    same grid mapping: fused == vmap bit-exact at every group width. A
+    group holds one reading scheme, so each scheme brings `rows` rows and
+    the sweep runs three groups of that width."""
+    specs = [SweepSpec(scheme=s, step_size=0.2, tau=3, num_threads=4,
+                       inner_steps=15, seed=c)
+             for s in SCHEMES for c in range(rows)]
+    groups = plan_sweep(obj, 2, specs).groups
+    assert sorted(len(m) for m in groups.values()) == [rows] * 3
     _assert_same(run_sweep(obj, 2, specs), run_sweep(obj, 2, _fused(specs)))
 
 

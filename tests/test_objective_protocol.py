@@ -17,6 +17,7 @@ Pins the contracts the objective generalization introduces:
     never mixes objectives; mixed-objective FLUSHES coalesce without ever
     sharing a compiled group.
 """
+import dataclasses
 import json
 import os
 import subprocess
@@ -154,8 +155,15 @@ def test_plan_rejects_mixed_objectives_in_one_sweep(obj, mlp):
 @pytest.mark.nonconvex
 def test_mlp_rows_batch_composition_independent(mlp):
     """A pytree objective inherits the engine's core guarantee: a row's
-    bits do not depend on which other rows share its vmapped group."""
-    specs = _mlp_specs()
+    bits do not depend on which other rows share its vmapped group. A
+    group holds one algo and one reading scheme, so each of the three
+    specs brings two more rows of its own (other seeds and steps): three
+    groups of width 3."""
+    specs = [dataclasses.replace(s, seed=s.seed + 10 * k,
+                                 step_size=s.step_size * (1 + k))
+             for s in _mlp_specs() for k in range(3)]
+    groups = plan_sweep(mlp, 2, specs).groups
+    assert sorted(len(m) for m in groups.values()) == [3, 3, 3]
     together = run_sweep(mlp, 2, specs)
     for c, spec in enumerate(specs):
         alone = run_sweep(mlp, 2, [spec])

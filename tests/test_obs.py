@@ -133,6 +133,7 @@ def test_service_records_complete_span_chain(obj):
         assert by_name["submit"]["tags"]["request_id"] == rid
         assert by_name["dispatch"]["tags"]["cache"] in ("hit", "miss")
         assert by_name["execute"]["tags"]["engine_mode"] in ("vmap", "fused")
+        assert by_name["execute"]["tags"]["scheme"] == "inconsistent"
         # one flush latency + one request latency + rows + pad factor
         for h in svc.histograms.as_dict().values():
             assert h.snapshot()[2] == 1

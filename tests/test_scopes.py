@@ -32,16 +32,17 @@ def obj():
 
 
 def _runner(obj, algo):
-    """The group function and its arguments for one group of three rows,
-    one per read scheme, over 2 epochs."""
-    specs = [SweepSpec(algo=algo, scheme=s, step_size=0.5, tau=3,
-                       num_threads=4, seed=k) for k, s in enumerate(SCHEMES)]
+    """The group function and its arguments for one group of three unlock
+    rows over 2 epochs. Every scheme's rows run this one program, which
+    holds each reader branch and the drop mask."""
+    specs = [SweepSpec(algo=algo, scheme="unlock", step_size=0.5, tau=3,
+                       num_threads=4, seed=k) for k in range(3)]
     plan = plan_sweep(obj, 2, specs)
     (key, members), = plan.groups.items()
-    _, engine, total, option, buf_len, _ = key
+    engine = key.engine
     fn, _ = _group_fn(engine, obj=obj, num_data=len(obj.data_args()),
-                      epochs=2, total=total, buf_len=buf_len, option=option,
-                      drop_prob=0.02)
+                      epochs=2, total=key.total, buf_len=key.buf_len,
+                      option=key.option, drop_prob=0.02)
     args = _row_args(engine, plan.specs, plan.resolved, members,
                      obj.init_flat())
     return fn, (*obj.data_args(), *args)

@@ -6,6 +6,7 @@ of the serving tier is that coalesced dispatch happens asynchronously on
 size/deadline policy, and every result is still bit-identical to a
 standalone ``run_sweep`` of that tenant's specs.
 """
+import dataclasses
 import sys
 import threading
 
@@ -102,6 +103,22 @@ def test_stable_widths_keep_warm_path_at_zero_compiles(obj):
     base = cache_stats()
     svc2.sweep(_specs([7, 8]))
     assert cache_stats().since(base).compiles >= 1
+
+
+def test_stable_widths_carry_across_schemes(obj):
+    """Groups that differ only in their scheme run one compiled program,
+    so a width one scheme compiled serves the next: 0 new traces."""
+    clear_cache()
+    svc = SweepService(obj, epochs=1, width_policy=WidthRegistry())
+    svc.submit(_specs([0, 1, 2]))             # inconsistent, width 3
+    svc.flush()
+    base = cache_stats()
+    unlock = [dataclasses.replace(s, scheme="unlock") for s in _specs([7, 8])]
+    rid = svc.submit(unlock)                  # width 2 -> padded to 3
+    svc.flush()
+    assert cache_stats().since(base).compiles == 0
+    assert svc.stats().rows_padded == 1
+    _assert_same(svc.result(rid), run_sweep(obj, 1, unlock))
 
 
 def test_width_registry_bounds_padding_waste():

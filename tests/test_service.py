@@ -19,6 +19,7 @@ import pytest
 
 from repro.checkpoint import Checkpointer
 from repro.core import LogisticRegression, SweepSpec, run_sweep
+from repro.core.asysvrg import SCHEME_IDS
 from repro.data.libsvm import make_synthetic_libsvm
 from repro.service import (
     ResultEvictedError,
@@ -136,20 +137,41 @@ def test_clear_cache_resets(obj):
 
 # ----------------------------------------------------------- scheduler layer
 def test_coalesce_merges_compatible_rows_across_requests(obj):
-    """Rows with equal static group dims pool into ONE group across
-    requests; incompatible rows (different M̃) stay separate."""
+    """Rows with equal static group dims and scheme pool into ONE group
+    across requests; incompatible rows (different M̃) stay separate, and
+    so does a row of another scheme."""
     svc = SweepService(obj, epochs=2)
-    svc.submit(_grid_a())                      # M̃ = 4*25
-    svc.submit([SweepSpec(scheme="unlock", step_size=1.0, tau=3,
+    svc.submit(_grid_a())                      # M̃ = 4*25, inconsistent
+    svc.submit([SweepSpec(scheme="inconsistent", step_size=1.0, tau=3,
                           num_threads=4, inner_steps=25, seed=9)])
     svc.submit([SweepSpec(scheme="unlock", step_size=1.0, tau=2,
                           num_threads=3, inner_steps=20, seed=9)])  # M̃ = 60
+    svc.submit([SweepSpec(scheme="unlock", step_size=1.0, tau=3,
+                          num_threads=4, inner_steps=25, seed=9)])
     batch = coalesce(obj, tuple(svc._pending))
     sizes = sorted(len(m) for m in batch.groups.values())
-    assert sizes == [1, 3]                     # 2+1 merged, 1 alone
+    assert sizes == [1, 1, 3]                  # 2+1 merged, 2 alone
+    unlock_m100 = [m for k, m in batch.groups.items()
+                   if k.total == 100 and k.scheme == "unlock"]
+    assert unlock_m100 == [[4]]                # its own group
     svc.flush()
     assert svc.stats().rows_coalesced == 3
     assert svc.stats().groups_merged == 1
+
+
+def test_scheme_sequence_runs_one_runner_as_one_group_per_scheme(obj):
+    """A tuner's consistent / inconsistent / unlock requests, one after
+    another: three groups, each of one scheme, through one compiled
+    runner (the scheme is no part of the runner key)."""
+    clear_cache()
+    svc = SweepService(obj, epochs=1)
+    for k, scheme in enumerate(SCHEME_IDS):
+        svc.sweep([SweepSpec(scheme=scheme, step_size=0.5, tau=3,
+                             num_threads=4, inner_steps=25, seed=k)])
+    stats = svc.stats()
+    assert (stats.cache_misses, stats.cache_hits, stats.compiles) == (1, 2, 1)
+    assert stats.groups_dispatched == 3
+    assert stats.groups_by_scheme == {s: 1 for s in SCHEME_IDS}
 
 
 def test_multi_request_coalescing_bit_identical(obj):

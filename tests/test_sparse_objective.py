@@ -77,7 +77,7 @@ def test_math_equals_the_dense_class_on_the_densified_rows(data, ell, what):
 
 def _coalesced(ell, specs):
     """Each row's (history, w) from a `SweepService` that took each row as
-    a request of its own and ran them in one group."""
+    a request of its own and pooled each scheme's rows in one group."""
     svc = SweepService(ell, epochs=2)
     rids = [svc.submit([spec]) for spec in specs]
     svc.flush()
@@ -88,9 +88,9 @@ def _coalesced(ell, specs):
 
 @pytest.mark.parametrize("path", ["run_sweep", "coalesced"])
 def test_rows_bits_do_not_depend_on_the_group(ell, path):
-    """Width 1 against width 3 with every read scheme in the group, through
-    `run_sweep` or through three requests the service coalesces."""
-    specs = [_spec(s, 40 + k) for k, s in enumerate(SCHEMES)]
+    """Width 1 against width 2, a group for each read scheme, through
+    `run_sweep` or through six requests the service coalesces."""
+    specs = [_spec(s, 40 + k) for k, s in enumerate(SCHEMES * 2)]
     if path == "run_sweep":
         res = run_sweep(ell, 2, specs)
         grouped = list(zip(res.histories, res.final_w))
@@ -141,10 +141,10 @@ def test_runner_names_the_ell_scopes(ell):
     specs = [_spec("unlock", 1)]
     plan = plan_sweep(ell, 1, specs)
     (key, members), = plan.groups.items()
-    _, engine, total, option, buf_len, _ = key
+    engine = key.engine
     fn, _ = _group_fn(engine, obj=ell, num_data=len(ell.data_args()),
-                      epochs=1, total=total, buf_len=buf_len, option=option,
-                      drop_prob=0.02)
+                      epochs=1, total=key.total, buf_len=key.buf_len,
+                      option=key.option, drop_prob=0.02)
     args = _row_args(engine, plan.specs, plan.resolved, members,
                      ell.init_flat())
     text = jax.jit(fn).lower(*ell.data_args(), *args).as_text(
@@ -188,10 +188,10 @@ def test_ell_grad_scope_holds_no_p_wide_work_but_its_scatters(ell):
     from repro.core.sweep import _group_fn, _row_args, plan_sweep
     plan = plan_sweep(ell, 1, [_spec("unlock", 1)])
     (key, members), = plan.groups.items()
-    _, engine, total, option, buf_len, _ = key
+    engine = key.engine
     fn, _ = _group_fn(engine, obj=ell, num_data=len(ell.data_args()),
-                      epochs=1, total=total, buf_len=buf_len, option=option,
-                      drop_prob=0.02)
+                      epochs=1, total=key.total, buf_len=key.buf_len,
+                      option=key.option, drop_prob=0.02)
     args = _row_args(engine, plan.specs, plan.resolved, members,
                      ell.init_flat())
     jaxpr = jax.make_jaxpr(fn)(*ell.data_args(), *args)

@@ -7,16 +7,19 @@
     for all three schemes;
 plus grouping across heterogeneous M̃ and delay-schedule dispatch checks.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (LogisticRegression, SweepSpec, make_grid,
-                        plan_sweep, run_asysvrg, run_sweep)
+                        plan_sweep, run_asysvrg, run_hogwild, run_sweep)
 from repro.core.asysvrg import (
-    DELAY_IDS, SCHEME_IDS, _READERS, _delay_schedule_core,
+    DELAY_IDS, SCHEME_IDS, _READERS, _delay_schedule_core, by_scheme,
     make_delay_schedule, read_dispatch)
+from repro.core.sweep import _group_fn, _row_args
 from repro.data.libsvm import make_synthetic_libsvm
 
 
@@ -26,10 +29,23 @@ def obj():
     return LogisticRegression(ds.X, ds.y, l2_reg=1e-3)
 
 
-def _assert_rows_match_sequential(obj, specs, res, epochs):
-    for c, spec in enumerate(specs):
-        seq = run_asysvrg(obj, epochs, spec.to_config(), seed=spec.seed,
-                          delay_kind=spec.delay_kind)
+def _sequential(obj, spec, epochs):
+    """The spec's run by its algorithm's own sequential driver."""
+    if spec.algo == "hogwild":
+        return run_hogwild(obj, epochs, spec.step_size,
+                           num_threads=spec.num_threads, decay=spec.decay,
+                           scheme=spec.scheme, tau=spec.tau, seed=spec.seed,
+                           delay_kind=spec.delay_kind)
+    return run_asysvrg(obj, epochs, spec.to_config(), seed=spec.seed,
+                       delay_kind=spec.delay_kind)
+
+
+def _assert_rows_match_sequential(obj, specs, res, epochs, rows=None):
+    """Rows ``rows`` (default: all) of ``res`` against ``specs``' own
+    sequential runs, bit for bit."""
+    for c in (range(len(specs)) if rows is None else rows):
+        spec = specs[c]
+        seq = _sequential(obj, spec, epochs)
         np.testing.assert_array_equal(
             np.asarray(seq.history, np.float32), res.histories[c],
             err_msg=f"history mismatch for {spec}")
@@ -73,7 +89,7 @@ def test_sweep_groups_heterogeneous_totals(obj):
 
 
 def test_read_dispatch_matches_direct_readers():
-    """lax.switch dispatch == the _READERS functions, same key, all schemes."""
+    """read_dispatch == the _READERS functions, same key, all schemes."""
     tau, dim = 4, 256
     buffer = jnp.tile(jnp.arange(tau + 1, dtype=jnp.float32)[:, None],
                       (1, dim))
@@ -92,22 +108,35 @@ def test_read_dispatch_matches_direct_readers():
 
 
 def test_read_dispatch_under_vmap_matches_per_scheme():
-    """One vmapped dispatch over all three scheme ids == three direct calls."""
+    """Per scheme, one read vmapped over rows (keys and ages) == the rows'
+    direct calls: a group's rows share its scheme and vary in the rest.
+    A vmap that batches the scheme id itself turns `by_scheme`'s switch
+    into a select: each row still reads by its own scheme."""
     tau, dim = 3, 64
     buffer = jnp.tile(jnp.arange(tau + 1, dtype=jnp.float32)[:, None],
                       (1, dim))
-    a, m = jnp.asarray(0), jnp.asarray(3)
-    key = jax.random.PRNGKey(23)
-    ids = jnp.asarray([0, 1, 2], jnp.int32)
-    batched = jax.vmap(
-        lambda sid: read_dispatch(sid, buffer, jnp.int32(tau), a, m, key,
-                                  dim))(ids)
+    a, m = jnp.asarray([0, 1, 2], jnp.int32), jnp.asarray(3)
+    keys = jax.random.split(jax.random.PRNGKey(23), 3)
     for scheme, sid in SCHEME_IDS.items():
-        direct = read_dispatch(jnp.int32(sid), buffer, jnp.int32(tau), a, m,
-                               key, dim)
-        np.testing.assert_array_equal(np.asarray(batched[sid]),
+        batched = jax.vmap(
+            lambda a_, k: read_dispatch(sid, buffer, jnp.int32(tau), a_, m,
+                                        k, dim))(a, keys)
+        for r in range(3):
+            direct = read_dispatch(sid, buffer, jnp.int32(tau), a[r], m,
+                                   keys[r], dim)
+            np.testing.assert_array_equal(np.asarray(batched[r]),
+                                          np.asarray(direct),
+                                          err_msg=f"scheme {scheme} row {r}")
+    ids = jnp.asarray(list(SCHEME_IDS.values()), jnp.int32)
+    selected = jax.vmap(lambda sid, a_, k: by_scheme(
+        sid, lambda s: read_dispatch(s, buffer, jnp.int32(tau), a_, m, k,
+                                     dim)))(ids, a, keys)
+    for scheme, sid in SCHEME_IDS.items():
+        direct = read_dispatch(sid, buffer, jnp.int32(tau), a[sid], m,
+                               keys[sid], dim)
+        np.testing.assert_array_equal(np.asarray(selected[sid]),
                                       np.asarray(direct),
-                                      err_msg=f"scheme {scheme}")
+                                      err_msg=f"batched scheme {scheme}")
 
 
 def test_numeric_delay_dispatch_matches_string_api():
@@ -249,7 +278,79 @@ def test_tau_axis_shares_one_group_at_fixed_thread_count(obj):
                        num_threads=4, inner_steps=25) for t in (1, 2, 3)]
     plan = plan_sweep(obj, 2, specs)
     assert len(plan.groups) == 1
-    (ofp, engine, total, option, buf_len, fused), = plan.groups
-    assert fused is False               # default engine mode is vmap
-    assert ofp == obj.fingerprint()
-    assert buf_len == 4                 # p, not max(τ)+1 of the members
+    (key,) = plan.groups
+    assert key.scheme_id == SCHEME_IDS["inconsistent"]
+    assert key.fused is False           # default engine mode is vmap
+    assert key.fingerprint == obj.fingerprint()
+    assert key.buf_len == 4             # p, not max(τ)+1 of the members
+
+
+def _computations(hlo: str):
+    """{computation name: its lines} of an HLO module's text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return {k: "\n".join(v) for k, v in comps.items()}
+
+
+@pytest.mark.parametrize("algo", ["asysvrg", "hogwild"])
+def test_one_scheme_group_runner_branches_on_its_scheme(obj, algo):
+    """A group holds one scheme, which its runner takes unbatched: the
+    switch on it around the inner loop compiles to a `conditional` of one
+    loop per scheme, so the device runs only the group's reader, and the
+    unlock mask only for unlock. A runner that batched the scheme would
+    compile it to a select that runs every scheme's loop."""
+    specs = [SweepSpec(algo=algo, scheme="inconsistent", step_size=0.5,
+                       tau=3, num_threads=4, inner_steps=25, seed=k)
+             for k in range(2)]
+    plan = plan_sweep(obj, 1, specs)
+    (key, members), = plan.groups.items()
+    engine = key.engine
+    fn, _ = _group_fn(engine, obj=obj, num_data=len(obj.data_args()),
+                      epochs=1, total=key.total, buf_len=key.buf_len,
+                      option=key.option, drop_prob=0.02)
+    args = _row_args(engine, plan.specs, plan.resolved, members,
+                     obj.init_flat())
+    hlo = jax.jit(fn).lower(*obj.data_args(), *args).compile().as_text()
+    comps = _computations(hlo)
+    branches = [re.findall(r"%?([\w.\-]+)", b) for b in re.findall(
+        r"\bconditional\(.*branch_computations=\{([^}]*)\}", hlo)]
+    by_scheme = [b for b in branches if len(b) == len(SCHEME_IDS)]
+    assert len(by_scheme) == 1
+    assert all(re.search(r"\bwhile\(", comps[c]) for c in by_scheme[0])
+
+
+def _mixed_grid():
+    """Both engines × the three schemes, two seeds each, in one call."""
+    return [SweepSpec(algo=algo, scheme=scheme, step_size=0.5, tau=3,
+                      num_threads=4, inner_steps=25, seed=seed)
+            for algo in ("asysvrg", "hogwild") for scheme in SCHEME_IDS
+            for seed in (5, 6)]
+
+
+@pytest.fixture(scope="module")
+def mixed_run(obj):
+    specs = _mixed_grid()
+    return specs, run_sweep(obj, 2, specs)
+
+
+@pytest.mark.parametrize("scheme", list(SCHEME_IDS))
+@pytest.mark.parametrize("algo", ["asysvrg", "hogwild"])
+def test_mixed_scheme_grid_rows_match_their_driver(obj, mixed_run, algo,
+                                                   scheme):
+    """A grid of both engines and every scheme dispatches one group per
+    (engine, scheme); each row equals its own `run_asysvrg` /
+    `run_hogwild` bit for bit."""
+    specs, res = mixed_run
+    plan = plan_sweep(obj, 2, specs)
+    assert len(plan.groups) == 6
+    rows = [c for c, s in enumerate(specs)
+            if s.algo == algo and s.scheme == scheme]
+    assert sorted(m for k, m in plan.groups.items()
+                  if k.scheme == scheme and k.engine == algo) == [rows]
+    _assert_rows_match_sequential(obj, specs, res, 2, rows)

@@ -23,6 +23,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.core import LogisticRegression, SweepSpec
+from repro.core.asysvrg import DROP_MASK_SCOPE, SCHEME_IDS
 from repro.core.sweep import (_group_fn, _pad_rows, _resolve, _row_args,
                               _shard_group_fn, plan_sweep)
 from repro.data.libsvm import PAPER_DATASETS
@@ -78,13 +79,14 @@ def _grid(algo):
             for st in (1.0, 2.0)]
 
 
-def _compile(algo, data_sharding, row_sharding, mesh=None):
-    """AOT-compile one group runner at rcv1 full size. Only shapes enter:
-    the objective's pure methods take the data at call time, and
-    `_resolve` reads nothing of the objective but n."""
+def _compile(algo, data_sharding, row_sharding, mesh=None, specs=None):
+    """AOT-compile one group runner at rcv1 full size, for ``specs`` (by
+    default `_grid`'s). Only shapes enter: the objective's pure methods
+    take the data at call time, and `_resolve` reads nothing of the
+    objective but n."""
     obj = LogisticRegression(np.zeros((1, DIM), np.float32),
                              np.ones(1, np.float32))
-    specs = _grid(algo)
+    specs = specs or _grid(algo)
     resolved = [_resolve(types.SimpleNamespace(n=N), s, EPOCHS)
                 for s in specs]
     r = resolved[0]
@@ -120,18 +122,19 @@ def test_vmap_asysvrg_runner_compiles_with_kernel(topo, tpu_backend):
 def test_compiled_kernel_call_carries_its_scopes(topo, tpu_backend):
     """A device trace names each op by its compiled instruction and keeps
     its name stack (``tf_op``): the kernel's call is the `svrg_update`
-    Pallas call inside the inner step, so a trace reader can count steps
-    by it."""
+    Pallas call inside the inner step, one in each scheme's inner loop,
+    so a trace reader can count steps by it."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     hlo = _compile("asysvrg", one_chip, one_chip).as_text()
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 1
-    op_name = re.search(r'op_name="([^"]*)"', calls[0]).group(1)
-    parts = op_name.split("/")
-    assert parts[-1] == "pallas_call"
-    assert parts.index("inner_step") < parts.index("svrg_update")
-    assert calls[0].lstrip().startswith("%svrg_update")
+    assert len(calls) == len(SCHEME_IDS)
+    for call in calls:
+        op_name = re.search(r'op_name="([^"]*)"', call).group(1)
+        parts = op_name.split("/")
+        assert parts[-1] == "pallas_call"
+        assert parts.index("inner_step") < parts.index("svrg_update")
+        assert call.lstrip().startswith("%svrg_update")
 
 
 def test_hogwild_runner_compiles(topo, tpu_backend):
@@ -185,6 +188,26 @@ def test_unlock_read_compiles_without_a_coordinate_gather(topo, tpu_backend,
     assert f"f32[{','.join(map(str, buffer_shape))}]" in hlo
     assert _coordinate_gathers(hlo, buffer_shape) == []
     assert "read_unlock" in hlo          # its ops keep the reader's scope
+
+
+def test_drop_mask_is_drawn_once_for_its_three_products(topo, tpu_backend):
+    """The unlock branch masks g, g0 and gf with one p-wide Bernoulli draw,
+    so exactly one p-wide fusion under the drop-mask scope computes the
+    draw's hash (threefry's ``xor``s). Left to itself, XLA on a TPU fuses
+    the draw into each product of a one-row group and draws it three times
+    a step."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    hlo = _compile("asysvrg", one_chip, one_chip, specs=[
+        SweepSpec(scheme="unlock", step_size=1.0, tau=9,
+                  num_threads=10)]).as_text()
+    bodies = dict(re.findall(r"^(%\S+) .*?\{\n(.*?)\n\}", hlo,
+                             re.MULTILINE | re.DOTALL))
+    drawing = [call for line in hlo.splitlines()
+               if f",{DIM}]" in line.partition(" fusion(")[0]
+               and f"/{DROP_MASK_SCOPE}/" in line
+               for call in re.findall(r"calls=(%[\w.\-]+)", line)
+               if " xor(" in bodies.get(call, "")]
+    assert len(drawing) == 1, drawing
 
 
 @pytest.mark.parametrize("how", ["spec", "env"])
