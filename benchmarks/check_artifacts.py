@@ -1,7 +1,7 @@
 """Validate every BENCH_*.json artifact before CI uploads it.
 
 The bench jobs are self-gating two ways: benchmarks with a correctness
-component (kernel_sweep parity, service_throughput warm-compile count)
+component (service_throughput's warm-compile count)
 raise inside ``main()``, and THIS checker catches the quieter failure mode
 — a benchmark that "succeeded" but wrote an artifact downstream tooling
 cannot consume. Every ``BENCH_*.json`` in the scanned directory must
@@ -29,7 +29,6 @@ from typing import Dict, List, Tuple
 # Names absent here get only the parse/object checks (new benchmarks work
 # out of the box; add their schema once a consumer depends on it).
 REQUIRED_KEYS: Dict[str, Tuple[str, ...]] = {
-    "kernel_sweep": ("backend", "fused_mode", "shapes"),
     "service_throughput": ("cold_s", "warm_s", "warm_cold_ratio",
                            "coalesced_speedup"),
     "server_latency": (),
@@ -52,29 +51,6 @@ REQUIRED_KEYS: Dict[str, Tuple[str, ...]] = {
     "repro_lint": ("files", "diagnostics", "suppressions", "rules"),
 }
 
-# kernel_sweep is additionally checked per shape: these are the keys the
-# roofline-vs-measured comparison needs (acceptance criterion: timings AND
-# predicted intensity for >= 2 group shapes).
-_KERNEL_SHAPE_KEYS = ("label", "rows", "inner_steps", "epochs", "vmap_s",
-                      "fused_s", "measured_speedup", "parity", "roofline")
-
-
-def _check_kernel_sweep(payload: dict) -> List[str]:
-    errs = []
-    shapes = payload.get("shapes")
-    if not isinstance(shapes, list) or len(shapes) < 2:
-        return [f"shapes: expected a list of >= 2 group shapes, "
-                f"got {shapes!r:.80}"]
-    for i, s in enumerate(shapes):
-        missing = [k for k in _KERNEL_SHAPE_KEYS
-                   if not isinstance(s, dict) or k not in s]
-        if missing:
-            errs.append(f"shapes[{i}]: missing keys {missing}")
-        elif "intensity_headroom" not in s["roofline"]:
-            errs.append(f"shapes[{i}].roofline: missing intensity_headroom")
-    return errs
-
-
 def check_file(path: str) -> List[str]:
     """All schema violations for one artifact (empty list = valid)."""
     name = os.path.basename(path)[len("BENCH_"):-len(".json")]
@@ -86,11 +62,8 @@ def check_file(path: str) -> List[str]:
         return [f"unparseable: {e}"]
     if not isinstance(payload, dict) or not payload:
         return ["top level must be a non-empty JSON object"]
-    errs = [f"missing required key {k!r}"
+    return [f"missing required key {k!r}"
             for k in REQUIRED_KEYS.get(name, ()) if k not in payload]
-    if name == "kernel_sweep" and not errs:
-        errs += _check_kernel_sweep(payload)
-    return errs
 
 
 def main(argv: List[str]) -> int:

@@ -13,13 +13,11 @@ import sys
 def main() -> None:
     quick = "--full" not in sys.argv
     from benchmarks import (fig1_convergence, fig1_speedup,
-                            frontier_stability, kernel_sweep,
-                            nonconvex_frontier, progress_ledger,
-                            roofline_report, server_latency,
-                            service_throughput, table2_schemes,
-                            table3_vs_hogwild)
+                            frontier_stability, nonconvex_frontier,
+                            progress_ledger, roofline_report,
+                            server_latency, service_throughput,
+                            table2_schemes, table3_vs_hogwild)
     table2_schemes.main(quick=quick)
-    kernel_sweep.main(quick=quick)
     table3_vs_hogwild.main(quick=quick)
     frontier_stability.main(quick=quick)
     nonconvex_frontier.main(quick=quick)

@@ -21,12 +21,12 @@ rows its device holds, and its difference from unsharded runs of each row
 alone and of the whole group is reported (on a TPU a row's bits depend on
 the vmap width).
 
-Every run refuses a non-TPU backend, a set ``REPRO_KERNEL_MODE`` or
-``REPRO_SWEEP_ENGINE``, and a `svrg_update` kernel that does not resolve
-to the compiled Pallas body. It checks that histories are finite and
-every row's loss fell, that each row's last loss matches a NumPy float64
-evaluation of f(final_w), and that the served (or sharded) results equal
-a direct `run_sweep` at the same width bit for bit. On one chip the
+Every run refuses a non-TPU backend, a set ``REPRO_KERNEL_MODE``, and a
+`svrg_update` kernel that does not resolve to the compiled Pallas body.
+It checks that histories are finite and every row's loss fell, that each
+row's last loss matches a NumPy float64 evaluation of f(final_w), and
+that the served (or sharded) results equal a direct `run_sweep` at the
+same width bit for bit. On one chip the
 AsySVRG group is also rerun with ``REPRO_KERNEL_MODE=reference`` set inside
 the process, which builds a separate runner around the jnp oracle of
 `svrg_update`; its rows must agree with the compiled kernel's within
@@ -70,7 +70,6 @@ LOSS_RTOL = 1e-4                 # float32 engine vs float64 NumPy f(w)
 # ~2.5e-4 and w by ~5e-4 of its largest entry
 REF_HIST_RTOL = 1e-5
 REF_W_RTOL = 1e-4
-ENGINE_ENV = "REPRO_SWEEP_ENGINE"
 RESULT_TIMEOUT_S = 1200.0
 
 
@@ -91,9 +90,8 @@ def emit(phase: str, **fields) -> None:
 def preflight(chips: int) -> dict:
     """Refuse anything but the compiled TPU path; returns the device as
     JAX reports it."""
-    for var in (KERNEL_MODE_ENV, ENGINE_ENV):
-        check(not os.environ.get(var),
-              f"${var} is set; the smoke runs only the default path")
+    check(not os.environ.get(KERNEL_MODE_ENV),
+          f"${KERNEL_MODE_ENV} is set; the smoke runs only the default path")
     backend = jax.default_backend()
     check(backend == "tpu", f"JAX backend is {backend!r}, not 'tpu'")
     devices = jax.devices()
@@ -178,7 +176,7 @@ def check_kernel_in_runner(obj, plan) -> None:
         runner = get_group_runner(engine, group_epochs=EPOCHS,
                                   total=key_.total, option=key_.option,
                                   buf_len=key_.buf_len, drop_prob=DROP_PROB,
-                                  mesh=None, obj=obj, fused=key_.fused)
+                                  mesh=None, obj=obj)
         args = _row_args(engine, plan.specs, plan.resolved, members, w_init)
         with uncounted_trace():
             hlo = runner.lower(*obj.data_args(), *args).as_text()

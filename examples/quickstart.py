@@ -23,12 +23,6 @@ below; examples/serve_sweeps.py is the full multi-tenant demo with
 priorities and a time-sliced giant job, examples/sweep_service.py the
 in-process + checkpoint-resume one).
 
-Fused kernel path: every group can also run as ONE Pallas megakernel
-launch (`engine_mode="fused"` per spec, or ``REPRO_SWEEP_ENGINE=fused``
-process-wide) with the config rows mapped onto the kernel grid — bit-exact
-to the default vmap engine in interpret mode; see the "fused kernel path"
-section below for when it profits and how to read the benchmark.
-
 Bring your own objective: the engine is not married to logistic regression.
 Subclass `repro.core.Objective` with three math methods (fixed-order loss,
 stable full gradient, stable per-sample gradient — see the class docstring
@@ -119,26 +113,6 @@ def main():
 
     print("\nAsySVRG reaches a much smaller gap at EQUAL effective passes —")
     print("the paper's Figure 1 (right) in one table, from one compile-set.")
-
-    # ---- fused kernel path: the SAME grid as one Pallas megakernel
-    # launch per group — rows on the kernel grid, the whole multi-epoch
-    # scan inside one launch so the iterate/snapshot/anchor state stays
-    # kernel-resident instead of streaming through memory every update.
-    # Flip it per spec (engine_mode="fused") or process-wide with
-    # REPRO_SWEEP_ENGINE=fused; off TPU it runs under the Pallas
-    # interpreter, BIT-EXACT to the vmap engine (asserted here). It
-    # profits when groups are wide or scans deep (the memory-bound
-    # regime): `python -m benchmarks.kernel_sweep` records measured
-    # vmap-vs-fused times next to the roofline-predicted intensity
-    # headroom (repro.launch.roofline.sweep_epoch_roofline) per shape.
-    import dataclasses
-
-    import numpy as np
-    fused = run_sweep(obj, 6, [dataclasses.replace(s, engine_mode="fused")
-                               for s in specs])
-    assert np.array_equal(fused.histories, res.histories)
-    print("\nfused megakernel path: same grid, one launch per group, "
-          "bit-exact to the vmap engine")
 
     # ---- serving sweeps: the same shapes again, served over HTTP. Two
     # tenants submit to a SweepServer and simply wait: the background

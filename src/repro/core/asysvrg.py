@@ -291,10 +291,10 @@ def _asysvrg_epochs_core(obj: Objective, data, w0, key, eta, tau, scheme_id,
     a shorter budget is bit-identical to an independent shorter run.
 
     This is the ONE definition of the per-row epochs scan: the sweep
-    engine's vmap path batches it (`repro.core.sweep._asysvrg_group_fn`)
-    and the fused Pallas megakernel runs it per grid row
-    (`repro.kernels.sweep_epoch`) — both paths execute literally this
-    function, which is what makes them bit-identical on XLA:CPU.
+    engine batches it over a group's rows with `jax.vmap`
+    (`repro.core.sweep._asysvrg_group_fn`), and under a mesh each device
+    runs that batch over its row shard (`_shard_group_fn`), so sharded
+    and unsharded rows execute literally this function.
     """
     with jax.named_scope(LOSS_SCOPE):
         loss0 = obj.flat_loss(data, w0)

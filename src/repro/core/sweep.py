@@ -96,8 +96,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-import os
-
 from repro.config import SVRGConfig
 from repro.core.asysvrg import (
     DELAY_IDS,
@@ -107,7 +105,6 @@ from repro.core.asysvrg import (
 )
 from repro.core.hogwild import _hogwild_epochs_core, _resolve_hogwild_steps
 from repro.core.objective import Objective, get_objective, params_from_flat
-from repro.kernels.dispatch import fused_sweep_mode
 from repro.obs.trace import tracer as _tracer
 from repro.sharding.context import current_mesh
 
@@ -117,29 +114,6 @@ _ENGINE_ASYSVRG = "asysvrg"
 _ENGINE_HOGWILD = "hogwild"
 _DATA_AXIS = "data"
 _SCHEME_NAMES = {i: name for name, i in SCHEME_IDS.items()}
-
-# engine modes: how a group's epoch scan executes. "vmap" batches the
-# per-row epochs cores with jax.vmap (per-update XLA op dispatch); "fused"
-# maps the row axis onto a Pallas grid and runs the whole (group × epoch)
-# scan as ONE megakernel launch (repro.kernels.sweep_epoch) — under the
-# Pallas interpreter, where it is BIT-IDENTICAL to the vmap path
-# (tests/test_kernel_sweep.py). On a TPU backend "fused" is refused at plan
-# time (`repro.kernels.dispatch.FUSED_TPU_BLOCKERS`). "" on a spec inherits
-# the process default: $REPRO_SWEEP_ENGINE, else "vmap".
-ENGINE_MODES = ("vmap", "fused")
-_ENGINE_MODE_ENV = "REPRO_SWEEP_ENGINE"
-
-
-def default_engine_mode() -> str:
-    """The process-wide engine mode specs with ``engine_mode=""`` resolve
-    to: ``$REPRO_SWEEP_ENGINE`` when set (validated), else "vmap" — the
-    fused megakernel is opt-in per spec or per process."""
-    mode = os.environ.get(_ENGINE_MODE_ENV, "").strip().lower()
-    if mode and mode not in ENGINE_MODES:
-        raise ValueError(
-            f"{_ENGINE_MODE_ENV}={mode!r} — expected one of {ENGINE_MODES}")
-    return mode or "vmap"
-
 
 @dataclasses.dataclass(frozen=True)
 class SweepSpec:
@@ -164,12 +138,6 @@ class SweepSpec:
     rectangular in its flat dim); submit separate requests to sweep several
     objectives — the service scheduler keeps them in distinct groups via
     the objective fingerprint in the group key.
-    ``engine_mode`` picks how the row's group executes: "vmap" (the
-    batched-XLA path) or "fused" (the Pallas sweep-epoch megakernel,
-    `repro.kernels.sweep_epoch`); "" inherits `default_engine_mode()`.
-    The mode joins the group key, so fused and vmap rows never share a
-    compiled runner — and their results are bit-identical in interpret
-    mode, so flipping the flag never changes a row's numbers on CPU.
     ``telemetry`` opts the row into `repro.obs.telemetry` series
     (realized staleness, update norms) on its `SweepResult`. It is pure
     reporting computed OUTSIDE the jitted group fn from already-returned
@@ -188,7 +156,6 @@ class SweepSpec:
     decay: float = 0.9
     epochs: int = 0
     objective: str = ""
-    engine_mode: str = ""
     telemetry: bool = False
 
     def to_config(self) -> SVRGConfig:
@@ -291,7 +258,6 @@ class _Resolved(NamedTuple):
     passes_per_epoch: float  # repro-lint: ignore[RL004] derived from engine+total+n (all keyed); pass-count accounting only, never shapes the compiled program
     buf_len: int         # ring-buffer length, pinned per-row (see _resolve)
     epochs: int          # this row's outer-epoch budget
-    fused: bool = False  # True = Pallas megakernel, False = vmap path
 
 
 def _row_buf_len(tau: int, num_threads: int, total: int) -> int:
@@ -323,10 +289,6 @@ def _normalize_spec(spec: SweepSpec) -> SweepSpec:
         raise ValueError(f"unknown delay schedule {spec.delay_kind!r}")
     if spec.epochs < 0:
         raise ValueError(f"epochs must be >= 0 (0 = inherit), got {spec.epochs}")
-    if spec.engine_mode and spec.engine_mode not in ENGINE_MODES:
-        raise ValueError(
-            f"unknown engine_mode {spec.engine_mode!r} "
-            f"(expected one of {ENGINE_MODES}, or '' to inherit)")
     if spec.algo == "svrg":
         if spec.tau != 0:
             raise ValueError(
@@ -344,14 +306,10 @@ def _resolve(obj: Objective, spec: SweepSpec,
     Raises (rather than letting a negative M̃ surface as a cryptic
     trace-time shape error) for non-positive resolved totals — this is the
     validation the service relies on to reject a bad spec at submit time.
-    A fused row on a TPU backend raises here too (`fused_sweep_mode`).
     """
     epochs = spec.epochs or default_epochs
     if epochs < 1:
         raise ValueError(f"resolved epochs must be >= 1, got {epochs}")
-    fused = (spec.engine_mode or default_engine_mode()) == "fused"
-    if fused:
-        fused_sweep_mode()
 
     if spec.algo == "hogwild":
         _, total, tau = _resolve_hogwild_steps(obj.n, spec.num_threads,
@@ -359,23 +317,20 @@ def _resolve(obj: Objective, spec: SweepSpec,
         delay_id = DELAY_IDS["zero"] if tau == 0 else DELAY_IDS[spec.delay_kind]
         res = _Resolved(_ENGINE_HOGWILD, total, tau,
                         SCHEME_IDS[spec.scheme], delay_id, 0, 1.0,
-                        _row_buf_len(tau, spec.num_threads, total), epochs,
-                        fused)
+                        _row_buf_len(tau, spec.num_threads, total), epochs)
     elif spec.algo == "svrg":
         # the zero-delay degenerate case on the asysvrg engine (paper §3)
         total = spec.inner_steps or 2 * obj.n
         res = _Resolved(_ENGINE_ASYSVRG, total, 0,
                         SCHEME_IDS["consistent"], DELAY_IDS["zero"],
                         spec.option, 1.0 + total / obj.n,
-                        _row_buf_len(0, spec.num_threads, total), epochs,
-                        fused)
+                        _row_buf_len(0, spec.num_threads, total), epochs)
     else:
         _, _, total, tau = _resolve_steps(obj, spec.to_config())
         delay_id = DELAY_IDS["zero"] if tau == 0 else DELAY_IDS[spec.delay_kind]
         res = _Resolved(_ENGINE_ASYSVRG, total, tau, SCHEME_IDS[spec.scheme],
                         delay_id, spec.option, 1.0 + total / obj.n,
-                        _row_buf_len(tau, spec.num_threads, total), epochs,
-                        fused)
+                        _row_buf_len(tau, spec.num_threads, total), epochs)
     if res.total < 1:
         raise ValueError(
             f"resolved inner-step count M̃ must be >= 1, got {res.total} "
@@ -389,8 +344,7 @@ def _executed_spec(spec: SweepSpec, r: _Resolved) -> SweepSpec:
     explicit, zero-delay collapse reflected, per-row epochs pinned)."""
     delay = "zero" if r.delay_id == DELAY_IDS["zero"] else spec.delay_kind
     return dataclasses.replace(spec, tau=r.tau, delay_kind=delay,
-                               epochs=r.epochs,
-                               engine_mode="fused" if r.fused else "vmap")
+                               epochs=r.epochs)
 
 
 class _GroupKey(NamedTuple):
@@ -409,7 +363,6 @@ class _GroupKey(NamedTuple):
     option: int
     buf_len: int
     scheme_id: int
-    fused: bool                # the resolved engine mode
 
     @property
     def scheme(self) -> str:
@@ -456,7 +409,7 @@ def plan_sweep(obj: Optional[Objective], epochs: int,
     """Normalize + resolve specs and group them by compiled-program shape.
 
     Exposed for tests and capacity planning: the group keys are the static
-    dims (objective fingerprint, engine, M̃, option, buf_len, fused) and
+    dims (objective fingerprint, engine, M̃, option, buf_len) and
     the row's resolved scheme id, all pinned per-row, so a row's key never
     depends on which other rows share the sweep. Rows of different schemes
     land in different groups of one compiled program. ``obj`` may be None
@@ -473,7 +426,7 @@ def plan_sweep(obj: Optional[Objective], epochs: int,
     for c, r in enumerate(resolved):
         groups.setdefault(
             _GroupKey(ofp, r.engine, r.total, r.option, r.buf_len,
-                      r.scheme_id, r.fused), []).append(c)
+                      r.scheme_id), []).append(c)
     return SweepPlan(specs=specs, resolved=resolved, groups=groups,
                      objective=obj)
 
@@ -566,22 +519,8 @@ def _hogwild_group_fn(obj: Objective, num_data: int, epochs: int, total: int,
 
 
 def _group_fn(engine: str, *, obj: Objective, num_data: int, epochs: int,
-              total: int, buf_len: int, option: int, drop_prob: float,
-              fused: bool = False):
-    """(unjitted group body, row-arg count) for the runner cache.
-
-    ``fused=True`` swaps the vmap batching for the Pallas sweep-epoch
-    megakernel (repro.kernels.sweep_epoch) — same calling convention, same
-    per-row epochs-scan functions, so in interpret mode the two bodies are
-    bit-identical.
-    """
-    if fused:
-        from repro.kernels.sweep_epoch import fused_group_fn
-        return (fused_group_fn(obj, num_data, engine=engine, epochs=epochs,
-                               total=total, buf_len=buf_len, option=option,
-                               drop_prob=drop_prob,
-                               interpret=fused_sweep_mode() == "interpret"),
-                _NUM_ROW_ARGS[engine])
+              total: int, buf_len: int, option: int, drop_prob: float):
+    """(unjitted group body, row-arg count) for the runner cache."""
     if engine == _ENGINE_HOGWILD:
         return (_hogwild_group_fn(obj, num_data, epochs, total, buf_len,
                                   drop_prob),
@@ -683,12 +622,11 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
     """
     from repro.service.cache import get_group_runner
 
-    engine, total, fused = key_.engine, key_.total, key_.fused
+    engine, total = key_.engine, key_.total
     args = _row_args(engine, specs, resolved, members, w_init)
     runner = get_group_runner(engine, group_epochs=group_epochs, total=total,
                               option=key_.option, buf_len=key_.buf_len,
-                              drop_prob=drop_prob, mesh=mesh, obj=obj,
-                              fused=fused)
+                              drop_prob=drop_prob, mesh=mesh, obj=obj)
     if mesh is not None:
         # pad the row axis to a multiple of the data-axis size; padded rows
         # replicate row 0 and are sliced off below
@@ -706,7 +644,7 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
         from repro.kernels.dispatch import mode_tags
         tags = dict(engine=engine, scheme=key_.scheme,
                     rows=len(members), total=int(total),
-                    group_epochs=int(group_epochs), **mode_tags(fused))
+                    group_epochs=int(group_epochs), **mode_tags())
     with tr.span_active("execute", **tags):
         w_fin, hist = runner(*obj.data_args(), *args)
         if tr.enabled:
@@ -718,9 +656,8 @@ def _dispatch_group(obj: Objective, specs: Sequence[SweepSpec],
 
 def group_label(key_: _GroupKey) -> str:
     """Human-readable label for one dispatched group (progress ids)."""
-    return (f"{key_.engine}-{'fused' if key_.fused else 'vmap'}"
-            f"-M{int(key_.total)}-opt{key_.option}-buf{int(key_.buf_len)}"
-            f"-{key_.scheme}")
+    return (f"{key_.engine}-M{int(key_.total)}-opt{key_.option}"
+            f"-buf{int(key_.buf_len)}-{key_.scheme}")
 
 
 def _assemble_result(specs: Tuple[SweepSpec, ...],

@@ -27,8 +27,7 @@ Modes:
   ``interpret`` — the kernel BODY executes under the Pallas interpreter
                   (plain XLA ops, any backend, bit-exact vs the same body
                   compiled only up to backend reduction order).
-  ``reference`` — the kernel's jnp ``ref.py`` oracle (or, for the fused
-                  sweep megakernel, the vmap engine) runs instead.
+  ``reference`` — the kernel's jnp ``ref.py`` oracle runs instead.
 """
 from __future__ import annotations
 
@@ -38,16 +37,6 @@ import jax
 
 KERNEL_MODE_ENV = "REPRO_KERNEL_MODE"
 _MODES = ("compiled", "interpret", "reference")
-
-
-# Why the fused megakernel cannot run on a TPU today (found by compiling
-# it for a described v5e topology; ROADMAP S6 holds the rewrite).
-FUSED_TPU_BLOCKERS = (
-    "the fused sweep-epoch megakernel does not compile for TPU: Mosaic "
-    "refuses its (1, 2) PRNG-key row block, which is not aligned to the "
-    "(8, 128) tiling, and every data array enters as one constant full "
-    "block, which puts the whole dataset in VMEM. Use engine_mode='vmap' "
-    "on TPU.")
 
 
 def env_mode() -> str:
@@ -86,31 +75,15 @@ def kernel_mode(interpret: bool = False, force_kernel: bool = False) -> str:
     return "reference"
 
 
-def fused_sweep_mode() -> str:
-    """'compiled' | 'interpret' for the fused sweep megakernel.
-
-    On a TPU backend this raises `FUSED_TPU_BLOCKERS`: the kernel does not
-    lower there, and substituting the interpreter or the vmap path would
-    hide that. Elsewhere the megakernel has no separate jnp reference — the
-    vmap engine IS its reference — so 'reference' is not a meaningful mode:
-    it runs the interpreter (bit-exact to the vmap path), as does the
-    default; a ``compiled`` override wins.
-    """
-    if kernel_backend() == "tpu":
-        raise ValueError(FUSED_TPU_BLOCKERS)
-    return "compiled" if env_mode() == "compiled" else "interpret"
-
-
-def mode_tags(fused: bool) -> dict:
+def mode_tags() -> dict:
     """Span tags describing HOW a group dispatch executes — stamped onto
     the tracer's ``execute`` spans by `repro.core.sweep._dispatch_group`
     so a trace answers "which lowering ran this request" without anyone
     re-deriving the mode later (it can change with the environment). The
-    resolution mirrors the runner-cache key (`_kernel_mode_key`): the
-    megakernel mode for fused bodies, the `svrg_update` mode for vmap."""
-    return {"engine_mode": "fused" if fused else "vmap",
-            "backend": kernel_backend(),
-            "kernel_mode": fused_sweep_mode() if fused else kernel_mode()}
+    ``kernel_mode`` tag is the runner-cache key's last facet
+    (`repro.service.cache.runner_key`): the `svrg_update` mode the group
+    body traces with."""
+    return {"backend": kernel_backend(), "kernel_mode": kernel_mode()}
 
 
 def use_pallas(interpret: bool = False, force_kernel: bool = False) -> bool:

@@ -360,91 +360,6 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig, defs) -> float:
 
 
 # ---------------------------------------------------------------------------
-# Fused sweep-epoch megakernel: analytic intensity headroom
-# ---------------------------------------------------------------------------
-
-def sweep_epoch_roofline(*, rows: int, dim: int, total: int, epochs: int,
-                         buf_len: int, hw: HardwareSpec = TPU_V5E,
-                         dtype_bytes: int = 4) -> Dict:
-    """Arithmetic-intensity headroom of the fused sweep-epoch megakernel
-    over the vmap engine for one (rows × epochs × M̃) group.
-
-    Both paths run the same FLOPs — per update, two component gradients
-    (~2·2·dim each for the dot + axpy shape shared by the repo's
-    objectives) plus the control-variate combine (~3·dim), ≈ 11·dim. What
-    differs is HBM traffic per update:
-
-      * vmap: the XLA scan carry — the iterate ``w``, the PRNG key + loss
-        slot, and the ``buf_len``-deep delay ring — is read AND written
-        through HBM every update, so bytes/update ≈ 2·(buf_len + 2)·dim·b
-        plus the sampled data row.
-      * fused: the carry lives in VMEM for the whole (row × epoch); only
-        the sampled data row moves per update, with the per-row boundary
-        I/O (w0 in, w_fin + history out) amortized over epochs·M̃ updates.
-
-    The intensity ratio is the roofline-predicted speedup bound in the
-    memory-bound regime (the AsySVRG inner loop's regime: intensity ~2
-    flops/byte << every listed hw's ridge). Returns both paths' terms so
-    benchmarks can log predicted vs measured side by side.
-    """
-    updates = float(rows) * epochs * total
-    flops_per_update = 11.0 * dim
-    flops = updates * flops_per_update
-    row_bytes = dim * dtype_bytes                       # sampled data row
-    carry_bytes = 2.0 * (buf_len + 2) * dim * dtype_bytes
-    boundary = rows * dtype_bytes * (2.0 * dim + epochs + 1)
-
-    out: Dict = {"rows": rows, "dim": dim, "total": total, "epochs": epochs,
-                 "buf_len": buf_len, "flops": flops}
-    for path, bytes_ in (("vmap", updates * (row_bytes + carry_bytes)
-                          + boundary),
-                         ("fused", updates * row_bytes + boundary)):
-        t_compute = flops / hw.peak_flops_bf16
-        t_memory = bytes_ / hw.hbm_bandwidth
-        out[path] = {
-            "bytes": bytes_,
-            "intensity_flops_per_byte": flops / bytes_,
-            "t_compute_s": t_compute,
-            "t_memory_s": t_memory,
-            "step_lower_bound_s": max(t_compute, t_memory),
-            "dominant": "compute" if t_compute >= t_memory else "memory",
-        }
-    out["intensity_headroom"] = (
-        out["fused"]["intensity_flops_per_byte"]
-        / out["vmap"]["intensity_flops_per_byte"])
-    out["predicted_speedup"] = (out["vmap"]["step_lower_bound_s"]
-                                / out["fused"]["step_lower_bound_s"])
-    return out
-
-
-def attained_fraction(*, rows: int, dim: int, total: int, epochs: int,
-                      buf_len: int, fused: bool, wall_s: float,
-                      hw: HardwareSpec = TPU_V5E) -> Dict:
-    """Attained-vs-roofline fraction for one MEASURED group dispatch.
-
-    Selects the engine path (vmap or fused megakernel) of
-    :func:`sweep_epoch_roofline` and divides its step lower bound by the
-    measured wall time — a per-group "how close to the hardware are
-    we" number. On a backend other than ``hw``
-    (e.g. the CPU CI container vs the TPU_V5E default) the fraction is a
-    cross-hardware comparison, not a utilization: still monotone in
-    dispatch speed, so regressions show, but only meaningful in absolute
-    terms when ``hw`` matches the machine.
-    """
-    rf = sweep_epoch_roofline(rows=rows, dim=dim, total=total,
-                              epochs=epochs, buf_len=buf_len, hw=hw)
-    path = rf["fused" if fused else "vmap"]
-    return {
-        "roofline_s": path["step_lower_bound_s"],
-        "attained_frac": (path["step_lower_bound_s"] / wall_s
-                          if wall_s > 0 else 0.0),
-        "flops": rf["flops"],
-        "bytes": path["bytes"],
-        "dominant": path["dominant"],
-    }
-
-
-# ---------------------------------------------------------------------------
 # Terms
 # ---------------------------------------------------------------------------
 

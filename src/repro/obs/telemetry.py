@@ -61,8 +61,7 @@ def realized_delays(seed: int, delay_id: int, tau: int, total: int,
     """[epochs, total] — the exact delay schedule the compiled scan drew.
 
     Replays the engines' key-split chain from ``PRNGKey(seed)`` (shared
-    verbatim by the asysvrg and hogwild epoch cores, and by the fused
-    Pallas megakernel, which runs the same ``*_core`` functions)."""
+    verbatim by the asysvrg and hogwild epoch cores)."""
     key = jax.random.PRNGKey(seed)
     delay_id_ = np.int32(delay_id)
     tau_ = np.int32(tau)

@@ -41,7 +41,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.core import sweep as _sweep
-from repro.kernels.dispatch import fused_sweep_mode, kernel_mode
+from repro.kernels.dispatch import kernel_mode
 from repro.obs.trace import tracer as _tracer
 from repro.sharding.context import mesh_fingerprint
 
@@ -136,33 +136,25 @@ _MAX_RUNNERS = 64
 
 _RunnerKey = Tuple  # (engine, M̃, option, buf_len, epochs, drop_prob,
 #                     mesh fingerprint, objective static key,
-#                     per-data-leaf (shape, dtype), (body, kernel mode))
-
-
-def _kernel_mode_key(fused: bool) -> Tuple[str, str]:
-    """The cache-key facet for the engine body and its RESOLVED kernel
-    lowering: ("vmap", the `svrg_update` mode the inner update traces
-    with) or ("fused", the megakernel mode). Resolving at key time means
-    flipping ``REPRO_KERNEL_MODE`` mid-process can never serve a runner
-    built for the other lowering."""
-    if fused:
-        return ("fused", fused_sweep_mode())
-    return ("vmap", kernel_mode())
+#                     per-data-leaf (shape, dtype), kernel mode)
 
 
 def runner_key(engine: str, *, group_epochs: int, total: int, option: int,
                buf_len: int, drop_prob: float, mesh: Optional[Mesh],
-               obj, fused: bool = False) -> _RunnerKey:
+               obj) -> _RunnerKey:
     """Everything that determines the compiled program. The objective's data
     enters the runner as arguments, so only its SHAPES/DTYPES are keyed
     (plus `obj.runner_static_key()`, the static config its pure methods
     close over) — two tenants sweeping same-shape datasets of one objective
-    class share one compiled program."""
+    class share one compiled program. The last facet is the RESOLVED
+    `svrg_update` lowering the body traces with, so flipping
+    ``REPRO_KERNEL_MODE`` mid-process can never serve a runner built for
+    another lowering."""
     data_sig = tuple((tuple(a.shape), str(jax.numpy.asarray(a).dtype))
                      for a in obj.data_args())
     return (engine, int(total), int(option), int(buf_len), int(group_epochs),
             float(drop_prob), mesh_fingerprint(mesh),
-            obj.runner_static_key(), data_sig, _kernel_mode_key(fused))
+            obj.runner_static_key(), data_sig, kernel_mode())
 
 
 def _counted(fn):
@@ -185,10 +177,9 @@ def _counted(fn):
 
 def get_group_runner(engine: str, *, group_epochs: int, total: int,
                      option: int, buf_len: int, drop_prob: float,
-                     mesh: Optional[Mesh], obj, fused: bool = False):
+                     mesh: Optional[Mesh], obj):
     """The jitted runner for one (engine, M̃, option, buf_len, …) group,
-    built at most once per key. ``fused=True`` keys and builds the Pallas
-    sweep-epoch megakernel body instead of the vmap body.
+    built at most once per key.
 
     The returned callable takes ``(*obj.data_args(), *row_args)`` with
     every row array row-leading; under a mesh it is shard_mapped over the
@@ -200,7 +191,7 @@ def get_group_runner(engine: str, *, group_epochs: int, total: int,
     """
     key = runner_key(engine, group_epochs=group_epochs, total=total,
                      option=option, buf_len=buf_len, drop_prob=drop_prob,
-                     mesh=mesh, obj=obj, fused=fused)
+                     mesh=mesh, obj=obj)
     num_data = len(obj.data_args())
     with _LOCK:
         runner = _RUNNERS.get(key)
@@ -214,8 +205,7 @@ def get_group_runner(engine: str, *, group_epochs: int, total: int,
         fn, num_row = _sweep._group_fn(engine, obj=obj, num_data=num_data,
                                        epochs=group_epochs,
                                        total=total, buf_len=buf_len,
-                                       option=option, drop_prob=drop_prob,
-                                       fused=fused)
+                                       option=option, drop_prob=drop_prob)
         if mesh is not None:
             fn = _sweep._shard_group_fn(fn, mesh, num_data, num_row)
         runner = jax.jit(_counted(fn))

@@ -292,7 +292,7 @@ def test_rl004_flags_key_param_never_read():
 KERNEL_IMPURE = """\
 import os
 
-def sweep_epoch_kernel(w_ref, o_ref):
+def update_kernel(w_ref, o_ref):
     print("tracing")
     mode = os.environ.get("REPRO_KERNEL_MODE")
     o_ref[...] = w_ref[...]

@@ -49,8 +49,7 @@ def test_compile_cache_defaults_to_ignored_repo_dir(config_updates,
 
 def _run_smoke(cwd: Path, tmp_path: Path, **env_extra):
     env = {k: v for k, v in os.environ.items()
-           if k not in ("REPRO_KERNEL_MODE", "REPRO_SWEEP_ENGINE",
-                        "PYTHONPATH")}
+           if k not in ("REPRO_KERNEL_MODE", "PYTHONPATH")}
     env.update(JAX_PLATFORMS="cpu",
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
                **env_extra)
@@ -72,7 +71,6 @@ def _printed_ok(stdout: str) -> bool:
 @pytest.mark.parametrize("env_extra, reason", [
     ({}, "not 'tpu'"),
     ({"REPRO_KERNEL_MODE": "reference"}, "REPRO_KERNEL_MODE"),
-    ({"REPRO_SWEEP_ENGINE": "vmap"}, "REPRO_SWEEP_ENGINE"),
 ])
 def test_chip_smoke_refuses_off_the_compiled_tpu_path(tmp_path, env_extra,
                                                       reason):

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import dispatch
 from repro.kernels.svrg_update import ops as svrg_ops
 from repro.kernels.svrg_update.ref import svrg_update_ref
 from repro.kernels.flash_attention import ops as flash_ops
@@ -125,3 +126,34 @@ def test_logreg_grad_is_true_gradient():
                                     force_kernel=True)
     np.testing.assert_allclose(np.asarray(g_kern), np.asarray(g_auto),
                                atol=1e-5)
+
+
+# ------------------------------------------------- unified kernel dispatch
+def test_kernel_mode_env_override_wins(monkeypatch):
+    """$REPRO_KERNEL_MODE beats flags and backend sniff for ALL kernels;
+    bad values raise."""
+    monkeypatch.setenv(dispatch.KERNEL_MODE_ENV, "interpret")
+    assert dispatch.kernel_mode() == "interpret"
+    assert dispatch.kernel_mode(force_kernel=True) == "interpret"
+    monkeypatch.setenv(dispatch.KERNEL_MODE_ENV, "reference")
+    assert dispatch.kernel_mode(interpret=True, force_kernel=True) \
+        == "reference"
+    monkeypatch.setenv(dispatch.KERNEL_MODE_ENV, "warp")
+    with pytest.raises(ValueError, match="REPRO_KERNEL_MODE"):
+        dispatch.kernel_mode()
+
+
+def test_kernel_mode_historical_contract(monkeypatch):
+    """Without the env var the unified helper reproduces the historical
+    per-kernel behaviour: kernel body iff force_kernel or TPU backend,
+    interpreter iff asked."""
+    monkeypatch.delenv(dispatch.KERNEL_MODE_ENV, raising=False)
+    monkeypatch.setattr(dispatch, "kernel_backend", lambda: "cpu")
+    assert dispatch.kernel_mode() == "reference"
+    assert dispatch.kernel_mode(interpret=True) == "reference"
+    assert dispatch.kernel_mode(interpret=True, force_kernel=True) \
+        == "interpret"
+    assert dispatch.kernel_mode(force_kernel=True) == "compiled"
+    monkeypatch.setattr(dispatch, "kernel_backend", lambda: "tpu")
+    assert dispatch.kernel_mode() == "compiled"
+    assert dispatch.kernel_mode(interpret=True) == "interpret"

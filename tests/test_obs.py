@@ -30,6 +30,7 @@ import pytest
 
 from repro.core import LogisticRegression, SweepSpec, run_sweep
 from repro.data.libsvm import make_synthetic_libsvm
+from repro.kernels import dispatch
 from repro.obs import Histogram, ServiceHistograms, Tracer
 from repro.obs import prometheus as obs_prometheus
 from repro.obs import telemetry as obs_telemetry
@@ -132,7 +133,8 @@ def test_service_records_complete_span_chain(obj):
         assert by_name["submit"]["tags"]["tenant"] == "team-a"
         assert by_name["submit"]["tags"]["request_id"] == rid
         assert by_name["dispatch"]["tags"]["cache"] in ("hit", "miss")
-        assert by_name["execute"]["tags"]["engine_mode"] in ("vmap", "fused")
+        assert (by_name["execute"]["tags"]["kernel_mode"]
+                == dispatch.kernel_mode())
         assert by_name["execute"]["tags"]["scheme"] == "inconsistent"
         # one flush latency + one request latency + rows + pad factor
         for h in svc.histograms.as_dict().values():

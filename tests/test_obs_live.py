@@ -7,8 +7,8 @@ The PR-10 contracts, bottom-up:
   * `Watchdog` — divergence detection on host-side numpy histories at
     slice/flush boundaries; ``cancel_row`` freezes the offender via the
     per-row epoch mask while every SURVIVOR stays bit-identical to a
-    watchdog-off run (vmap and fused engines; the sharded variant lives
-    in tests/test_sweep_sharded.py); ``cancel_job`` raises `JobDiverged`
+    watchdog-off run (the sharded variant lives in
+    tests/test_sweep_sharded.py); ``cancel_job`` raises `JobDiverged`
     from ``run_job`` but degrades to ``cancel_row`` inside a coalesced
     multi-tenant flush.
   * Progress events — per-slice/-flush loss series equal the final
@@ -117,8 +117,8 @@ def test_progress_event_round_trips_json():
     import json
     bus = ProgressBus()
     ev = bus.publish(kind="slice", watch_id="job-1", tenant="t",
-                     group="asysvrg-vmap-M100-opt2-buf4", slice_index=2,
-                     slices_total=3, rows=(4, 5),
+                     group="asysvrg-M100-opt2-buf4-inconsistent",
+                     slice_index=2, slices_total=3, rows=(4, 5),
                      losses=((0.5, 0.25), (0.5, 0.125)),
                      loss_deltas=((-0.25,), (-0.375,)), diverged=(5,),
                      wall_s=0.125, trace_id="t01")
@@ -154,18 +154,14 @@ def test_watchdog_validation_and_tenant_policy():
     assert wd.policy_for("anyone-else") == "record"
 
 
-@pytest.mark.parametrize("engine_mode", ["vmap", "fused"])
-def test_flush_cancel_row_survivors_bit_identical(obj, engine_mode):
+def test_flush_cancel_row_survivors_bit_identical(obj):
     """THE bit-identity contract: one poisoned row in a shared compiled
     group gets cancelled (frozen at w0 — its first bad epoch is 1), and
     every surviving row's history AND final iterate are bit-identical to
     a watchdog-off `run_sweep` of the same specs. The freeze re-dispatch
     rides the per-row epoch mask, so it must not compile anything."""
-    import dataclasses
-    good = [dataclasses.replace(s, engine_mode=engine_mode)
-            for s in _specs([0, 1, 2])]
-    bad = dataclasses.replace(_specs([99], step_size=BAD_STEP)[0],
-                              engine_mode=engine_mode)
+    good = _specs([0, 1, 2])
+    bad = _specs([99], step_size=BAD_STEP)[0]
     specs = [good[0], bad, good[1], good[2]]
 
     svc = SweepService(obj, epochs=3, watchdog=Watchdog(policy="cancel_row"))

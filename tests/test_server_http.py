@@ -127,6 +127,25 @@ def test_error_mapping(served):
                                                max_delay_ms=25)
 
 
+def test_http_refuses_a_spec_naming_engine_mode(served, obj):
+    """A spec that names a field SweepSpec does not have (here the removed
+    ``engine_mode``, which older clients send) gets a 400 naming it, and
+    the server goes on serving."""
+    svc, server, client = served
+    spec = {**dataclasses.asdict(_specs([50])[0]), "engine_mode": "vmap"}
+    req = urllib.request.Request(
+        server.url + "/submit", method="POST",
+        data=json.dumps({"specs": [spec]}).encode(),
+        headers={"Content-Type": "application/json"})
+    with pytest.raises(urllib.error.HTTPError) as err:
+        urllib.request.urlopen(req)
+    assert err.value.code == 400
+    assert "engine_mode" in err.value.read().decode()
+    assert client.healthz()["status"] == "ok"
+    _assert_same(client.sweep(_specs([51]), timeout=180),
+                 run_sweep(obj, 1, _specs([51])))
+
+
 def test_unknown_route_404(served):
     svc, server, client = served
     with urllib.request.urlopen(server.url + "/healthz") as resp:

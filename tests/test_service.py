@@ -122,9 +122,39 @@ def test_vmap_runner_keyed_on_kernel_mode(obj, monkeypatch):
     monkeypatch.setenv(KERNEL_MODE_ENV, "interpret")
     interpret = get_group_runner("asysvrg", **k)
     assert interpret is not reference
-    assert runner_key("asysvrg", **k)[-1] == ("vmap", "interpret")
+    assert runner_key("asysvrg", **k)[-1] == "interpret"
     monkeypatch.setenv(KERNEL_MODE_ENV, "reference")
     assert get_group_runner("asysvrg", **k) is reference
+
+
+_KERNEL_MODE_SETTINGS = (None, "interpret", "reference")
+
+
+def _key_under(monkeypatch, setting, k):
+    """(runner key, resolved kernel mode) with $REPRO_KERNEL_MODE set to
+    ``setting`` (None = unset)."""
+    from repro.kernels.dispatch import KERNEL_MODE_ENV, kernel_mode
+    if setting is None:
+        monkeypatch.delenv(KERNEL_MODE_ENV, raising=False)
+    else:
+        monkeypatch.setenv(KERNEL_MODE_ENV, setting)
+    return runner_key("asysvrg", **k), kernel_mode()
+
+
+@pytest.mark.parametrize("setting", _KERNEL_MODE_SETTINGS)
+def test_runner_key_pins_resolved_kernel_mode(obj, monkeypatch, setting):
+    """The runner key's last facet is the RESOLVED `svrg_update` lowering,
+    so two settings of $REPRO_KERNEL_MODE share a runner exactly when they
+    resolve to one lowering (unset resolves to the jnp reference on the
+    CPU, to the compiled kernel on a TPU)."""
+    k = dict(group_epochs=2, total=100, option=2, buf_len=4,
+             drop_prob=0.02, mesh=None, obj=obj)
+    key, mode = _key_under(monkeypatch, setting, k)
+    assert key[-1] == mode
+    for other in _KERNEL_MODE_SETTINGS:
+        other_key, other_mode = _key_under(monkeypatch, other, k)
+        assert (other_key == key) == (other_mode == mode)
+        assert other_key[:-1] == key[:-1]
 
 
 def test_clear_cache_resets(obj):

@@ -280,7 +280,6 @@ def test_tau_axis_shares_one_group_at_fixed_thread_count(obj):
     assert len(plan.groups) == 1
     (key,) = plan.groups
     assert key.scheme_id == SCHEME_IDS["inconsistent"]
-    assert key.fused is False           # default engine mode is vmap
     assert key.fingerprint == obj.fingerprint()
     assert key.buf_len == 4             # p, not max(τ)+1 of the members
 
@@ -354,3 +353,23 @@ def test_mixed_scheme_grid_rows_match_their_driver(obj, mixed_run, algo,
     assert sorted(m for k, m in plan.groups.items()
                   if k.scheme == scheme and k.engine == algo) == [rows]
     _assert_rows_match_sequential(obj, specs, res, 2, rows)
+
+
+@pytest.mark.parametrize("width", [3, 8])
+@pytest.mark.parametrize("algo", ["asysvrg", "hogwild", "svrg"])
+def test_group_width_does_not_change_a_rows_bits(obj, algo, width):
+    """A one-scheme group of ``width`` rows gives every row the same
+    history and final iterate bits as that row run alone (width 1)."""
+    tau = 0 if algo == "svrg" else 3
+    specs = [SweepSpec(algo=algo, scheme="unlock", step_size=0.25 + 0.125 * c,
+                       tau=tau, num_threads=4, inner_steps=20, seed=c)
+             for c in range(width)]
+    assert [len(m) for m in plan_sweep(obj, 2, specs).groups.values()] \
+        == [width]
+    res = run_sweep(obj, 2, specs)
+    for c, spec in enumerate(specs):
+        alone = run_sweep(obj, 2, [spec])
+        np.testing.assert_array_equal(res.histories[c], alone.histories[0],
+                                      err_msg=f"history of {spec}")
+        np.testing.assert_array_equal(res.final_w[c], alone.final_w[0],
+                                      err_msg=f"final iterate of {spec}")

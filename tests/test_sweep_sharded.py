@@ -11,8 +11,6 @@ divide the device count and sizes that need padding, and composed with
 masked per-row epochs. This is the XLA:CPU calibration of the bit-exactness
 contract; re-validate per backend before trusting it on TPU/GPU.
 """
-import dataclasses
-
 import jax
 import numpy as np
 import pytest
@@ -275,59 +273,37 @@ def test_pytree_objectives_http_sharded_bit_identical(mesh):
         unregister_objective("sharded-test-ncv")
 
 
-def test_fused_engine_sharded_matches_vmap_unsharded(obj, mesh):
-    """The fused Pallas megakernel path (interpret mode on this CPU host)
-    composes with shard_map row sharding: a sharded fused sweep over all
-    three algos — at a row count that needs padding under 8 devices, with
-    mixed per-row epoch budgets — is bit-identical to the unsharded VMAP
-    path, closing fused==vmap and sharded==unsharded in one assertion."""
-    specs = [SweepSpec(scheme=SCHEMES[c % 3], step_size=0.5, tau=3,
-                       num_threads=4, inner_steps=25, seed=c,
-                       epochs=(c % 2) + 1)
-             for c in range(3)]
-    specs += [SweepSpec(algo="hogwild", scheme="unlock", step_size=0.5,
-                        tau=2, num_threads=3, seed=8),
-              SweepSpec(algo="svrg", step_size=0.5, inner_steps=30, seed=9)]
-    fused = [dataclasses.replace(s, engine_mode="fused") for s in specs]
-    base = run_sweep(obj, 2, specs)
-    shard_fused = run_sweep(obj, 2, fused, mesh=mesh)
-    _assert_same(base, shard_fused)
-
-
 def test_watchdog_cancel_row_sharded_survivors_bit_identical(obj, mesh):
-    """Divergence watchdog under the forced 8-device mesh, vmap AND fused
-    engines: the step_size=1e30 row NaNs on epoch 1 and is cancelled
-    (cancel_row), while every surviving row stays bit-identical to a
-    watchdog-off run — sharded and unsharded."""
+    """Divergence watchdog under the forced 8-device mesh: the
+    step_size=1e30 row NaNs on epoch 1 and is cancelled (cancel_row),
+    while every surviving row stays bit-identical to a watchdog-off run —
+    sharded and unsharded."""
     from repro.obs.watchdog import Watchdog
     from repro.service import SweepService
 
-    for engine_mode in ("vmap", "fused"):
-        good = [SweepSpec(scheme=SCHEMES[c % 3], step_size=0.5, tau=3,
-                          num_threads=4, inner_steps=25, seed=c,
-                          engine_mode=engine_mode)
-                for c in range(3)]
-        bad = SweepSpec(scheme="inconsistent", step_size=1e30, tau=3,
-                        num_threads=4, inner_steps=25, seed=99,
-                        engine_mode=engine_mode)
-        specs = good + [bad]
+    good = [SweepSpec(scheme=SCHEMES[c % 3], step_size=0.5, tau=3,
+                      num_threads=4, inner_steps=25, seed=c)
+            for c in range(3)]
+    bad = SweepSpec(scheme="inconsistent", step_size=1e30, tau=3,
+                    num_threads=4, inner_steps=25, seed=99)
+    specs = good + [bad]
 
-        svc = SweepService(obj, epochs=2, mesh=mesh,
-                           watchdog=Watchdog(policy="cancel_row"))
-        rid = svc.submit(specs)
-        svc.flush()
-        got = svc.result(rid)
+    svc = SweepService(obj, epochs=2, mesh=mesh,
+                       watchdog=Watchdog(policy="cancel_row"))
+    rid = svc.submit(specs)
+    svc.flush()
+    got = svc.result(rid)
 
-        assert got.diverged_rows is not None
-        assert np.flatnonzero(got.diverged_rows >= 0).tolist() == [3]
-        assert got.epochs_per_row[3] == 0          # frozen at w0
-        assert np.isfinite(got.histories[3]).all()
+    assert got.diverged_rows is not None
+    assert np.flatnonzero(got.diverged_rows >= 0).tolist() == [3]
+    assert got.epochs_per_row[3] == 0          # frozen at w0
+    assert np.isfinite(got.histories[3]).all()
 
-        ref_sharded = run_sweep(obj, 2, good, mesh=mesh)
-        ref_unsharded = run_sweep(obj, 2, good)
-        for ref in (ref_sharded, ref_unsharded):
-            np.testing.assert_array_equal(got.histories[:3], ref.histories)
-            np.testing.assert_array_equal(got.final_w[:3], ref.final_w)
+    ref_sharded = run_sweep(obj, 2, good, mesh=mesh)
+    ref_unsharded = run_sweep(obj, 2, good)
+    for ref in (ref_sharded, ref_unsharded):
+        np.testing.assert_array_equal(got.histories[:3], ref.histories)
+        np.testing.assert_array_equal(got.final_w[:3], ref.final_w)
 
 
 def test_model_axis_mesh_degrades_to_unsharded(obj):

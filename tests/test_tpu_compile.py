@@ -65,7 +65,6 @@ def topo():
 def tpu_backend(monkeypatch):
     """The kernel dispatch sees a TPU, so the Pallas body traces compiled."""
     monkeypatch.delenv(dispatch.KERNEL_MODE_ENV, raising=False)
-    monkeypatch.delenv("REPRO_SWEEP_ENGINE", raising=False)
     monkeypatch.setattr(dispatch, "kernel_backend", lambda: "tpu")
 
 
@@ -210,22 +209,27 @@ def test_drop_mask_is_drawn_once_for_its_three_products(topo, tpu_backend):
     assert len(drawing) == 1, drawing
 
 
-@pytest.mark.parametrize("how", ["spec", "env"])
-def test_fused_engine_on_tpu_raises_at_plan_time(tpu_backend, monkeypatch,
-                                                  how):
-    """Choosing the fused engine on a TPU backend names the Mosaic blockers
-    at plan time; it never falls back to the interpreter or to vmap."""
+@pytest.mark.parametrize("algo", ["asysvrg", "hogwild", "svrg"])
+def test_tpu_backend_plans_every_algo_on_the_compiled_kernel(tpu_backend,
+                                                            algo):
+    """On a TPU backend every algo plans, and each group's runner is keyed
+    on the compiled `svrg_update` kernel, never the interpreter or the
+    jnp reference."""
+    from repro.service.cache import runner_key
     obj = LogisticRegression(np.ones((8, 4), np.float32),
                              np.ones(8, np.float32))
-    spec = SweepSpec(inner_steps=4)
-    if how == "spec":
-        spec = SweepSpec(inner_steps=4, engine_mode="fused")
-    else:
-        monkeypatch.setenv("REPRO_SWEEP_ENGINE", "fused")
-    with pytest.raises(ValueError, match="Mosaic"):
-        plan_sweep(obj, 1, [spec])
-    with pytest.raises(ValueError, match="Mosaic"):
-        dispatch.fused_sweep_mode()
+    specs = [SweepSpec(algo=algo, scheme=s, inner_steps=4,
+                       tau=-1 if algo == "hogwild" else 0)
+             for s in ("consistent", "inconsistent", "unlock")]
+    plan = plan_sweep(obj, 1, specs)
+    assert {k.engine for k in plan.groups} == {
+        "hogwild" if algo == "hogwild" else "asysvrg"}
+    for key in plan.groups:
+        rkey = runner_key(key.engine, group_epochs=plan.group_epochs(key),
+                          total=key.total, option=key.option,
+                          buf_len=key.buf_len, drop_prob=0.02, mesh=None,
+                          obj=obj)
+        assert rkey[-1] == "compiled"
 
 
 def test_interpret_override_on_tpu_raises(tpu_backend, monkeypatch):
